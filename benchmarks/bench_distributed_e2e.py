@@ -18,47 +18,36 @@ The cluster engine's selling points, measured from the session itself:
                  sharded slowdown to named phases (bucketing, dispatch,
                  halo exchange, quota collective, kernel, host sync).
 
-Must launch with enough devices; the script re-execs itself with
-``XLA_FLAGS=--xla_force_host_platform_device_count=<k>`` if the host
-doesn't already expose them.
+Where JAX may run on the CPU the script first asks it for 8 fake host
+devices (``XLA_FLAGS=--xla_force_host_platform_device_count=8``, appended
+unless a count is set) and runs the scenario's k. On an accelerator host it
+runs one partition per device, up to the scenario's k (k=4 on a four-chip
+host), and refuses to run on fewer than two.
 
   PYTHONPATH=src:. python benchmarks/bench_distributed_e2e.py --scale smoke
 """
 from __future__ import annotations
 
 import argparse
-import os
-import subprocess
-import sys
-import time
-
-K_DEFAULT = 8
-
-if __name__ == "__main__" and "_REPRO_REEXEC" not in os.environ:
-    # the fake-device count must be pinned before jax initialises
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_count="
-                            + str(K_DEFAULT)).strip()
-        env["_REPRO_REEXEC"] = "1"
-        raise SystemExit(subprocess.call([sys.executable, *sys.argv], env=env))
-
 import dataclasses
+import os
+import time
 
 import numpy as np
 
-from benchmarks.common import RESULTS_DIR, save
+from benchmarks.common import RESULTS_DIR, run_main, save
 from repro.api import DynamicGraphSystem
 from repro.scenarios import SCENARIOS
 
 SCALES = {"smoke": 12, "small": 40, "full": None}   # max supersteps
 
 
-def run_one(scn, *, cluster: str, max_supersteps):
+def run_one(scn, *, k: int, cluster: str, max_supersteps):
     cfg = scn.system_config(strategy="xdgp", cluster=cluster)
-    cfg = dataclasses.replace(cfg, telemetry=dataclasses.replace(
-        cfg.telemetry, trace=True, trace_comm_probe=True))
+    cfg = dataclasses.replace(
+        cfg, partition=dataclasses.replace(cfg.partition, k=k),
+        telemetry=dataclasses.replace(cfg.telemetry, trace=True,
+                                      trace_comm_probe=True))
     if cluster == "sharded":
         # the scenario streams through its growth phase, so give the
         # padded buckets doubling head-room: shapes jump O(log) times
@@ -100,10 +89,18 @@ def main() -> None:
     scn = SCENARIOS[args.scenario](
         "smoke" if args.scale == "smoke" else "small", seed=0)
     max_ss = SCALES[args.scale]
+    # partition-per-device: as many partitions as devices, up to the
+    # scenario's own k (the fake CPU devices always cover it)
+    import jax
+    devices, platform = jax.device_count(), jax.default_backend()
+    k = scn.k if platform == "cpu" else min(scn.k, devices)
+    if k < 2 or devices < k:
+        raise SystemExit(f"sharded-vs-local needs {max(k, 2)} devices; JAX "
+                         f"has {devices} {platform} device(s)")
 
-    local_row, local_labels, local_tr = run_one(scn, cluster="local",
+    local_row, local_labels, local_tr = run_one(scn, k=k, cluster="local",
                                                 max_supersteps=max_ss)
-    shard_row, shard_labels, shard_tr = run_one(scn, cluster="sharded",
+    shard_row, shard_labels, shard_tr = run_one(scn, k=k, cluster="sharded",
                                                 max_supersteps=max_ss)
 
     bit_identical = bool(np.array_equal(local_labels, shard_labels))
@@ -132,7 +129,7 @@ def main() -> None:
 
     payload = {
         "scenario": scn.name,
-        "k": scn.k,
+        "k": k,
         "scale": args.scale,
         "events": scn.n_events,
         "assignments_bit_identical": bit_identical,
@@ -157,7 +154,7 @@ def main() -> None:
         os.path.join(RESULTS_DIR, "trace_distributed_e2e.trace.json"))
     sum_l, sum_s = local_tr.phase_totals(), shard_tr.phase_totals()
     gap = {
-        "scenario": scn.name, "k": scn.k, "scale": args.scale,
+        "scenario": scn.name, "k": k, "scale": args.scale,
         "wall_local_s": local_row["wall_seconds"],
         "wall_sharded_s": shard_row["wall_seconds"],
         "slowdown": shard_row["wall_seconds"] / local_row["wall_seconds"],
@@ -181,7 +178,7 @@ def main() -> None:
         print(f"{name:<24} {tl * 1e3:9.1f}ms {ts * 1e3:9.1f}ms")
     print(f"traces -> {local_trace}, {shard_trace}")
 
-    print(f"scenario={scn.name} k={scn.k} scale={args.scale}")
+    print(f"scenario={scn.name} k={k} scale={args.scale}")
     print(f"  parity: assignments bit-identical={bit_identical} "
           f"cut trajectories identical={cuts_identical}")
     print(f"  compile cache: {compiles}/{len(dispatches)} dispatches "
@@ -207,4 +204,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    main()
+    from repro.compat import request_host_devices
+    request_host_devices(8)         # before JAX initialises its backends
+    run_main(main)

@@ -34,7 +34,7 @@ from typing import Dict, List
 import jax
 import numpy as np
 
-from benchmarks.common import save
+from benchmarks.common import run_main, save
 from repro import compat
 from repro.core.initial import initial_partition
 from repro.core.partition_state import make_state
@@ -159,4 +159,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    main()
+    run_main(main)

@@ -23,7 +23,7 @@ from typing import Any, Dict, List
 
 import numpy as np
 
-from benchmarks.common import save
+from benchmarks.common import run_main, save
 
 SCALES = {
     "smoke": {"sizes": [200_000], "steps": 3, "adapt_iters": 3},
@@ -176,4 +176,4 @@ def main(argv: List[str] = None) -> Dict[str, Any]:
 
 
 if __name__ == "__main__":
-    main()
+    run_main(main)

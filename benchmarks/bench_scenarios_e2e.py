@@ -26,7 +26,7 @@ import argparse
 import time
 from typing import Dict, List
 
-from benchmarks.common import save
+from benchmarks.common import run_main, save
 from repro.scenarios import SCENARIOS, CostModel, compare_scenario
 
 
@@ -105,4 +105,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    main()
+    run_main(main)

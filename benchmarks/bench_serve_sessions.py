@@ -32,7 +32,7 @@ from typing import Any, Dict, List
 
 import numpy as np
 
-from benchmarks.common import save
+from benchmarks.common import run_main, save
 from repro.api import SystemConfig
 from repro.serve import (AdmissionPolicy, GraphServer, OpenLoopLoad,
                          TrafficShape, synthetic_stream)
@@ -138,6 +138,8 @@ def kill_recover_drill(n_tenants: int, *, quick: bool) -> Dict[str, Any]:
              "--config", cfg_path],
             capture_output=True, text=True, env=env, cwd=REPO, timeout=900)
 
+    # every drill process (the reference too) is a child of its own: the
+    # accelerator belongs to one process at a time
     victim = run("run")
     if victim.returncode != -signal.SIGKILL:
         raise RuntimeError(f"drill run did not die by SIGKILL "
@@ -145,7 +147,9 @@ def kill_recover_drill(n_tenants: int, *, quick: bool) -> Dict[str, Any]:
     rec = run("recover")
     if rec.returncode != 0:
         raise RuntimeError(f"drill recover failed: {rec.stderr}")
-    drill.cmd_reference(cfg)
+    ref = run("reference")
+    if ref.returncode != 0:
+        raise RuntimeError(f"drill reference failed: {ref.stderr}")
     with open(os.path.join(workdir, "recovered.json")) as f:
         recovered = json.load(f)
     with open(os.path.join(workdir, "reference.json")) as f:
@@ -166,8 +170,11 @@ def kill_recover_drill(n_tenants: int, *, quick: bool) -> Dict[str, Any]:
 def run(quick: bool = False) -> Dict[str, Any]:
     n_tenants = 8
     n_events = 1500 if quick else 4000
+    # the drill's processes need the accelerator, which this process holds
+    # from its first JAX computation on: run them before it has one
+    recovery = kill_recover_drill(n_tenants, quick=quick)
     payload = serve_open_loop(n_tenants, n_events, quick=quick)
-    payload["recovery"] = kill_recover_drill(n_tenants, quick=quick)
+    payload["recovery"] = recovery
     return payload
 
 
@@ -190,4 +197,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    main()
+    run_main(main)

@@ -29,7 +29,7 @@ import argparse
 import time
 from typing import Dict, List
 
-from benchmarks.common import save
+from benchmarks.common import run_main, save
 from repro.api import canonical_strategy_names
 from repro.scenarios import ARENA_SCENARIOS, CostModel, compare_scenario
 
@@ -134,4 +134,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    main()
+    run_main(main)

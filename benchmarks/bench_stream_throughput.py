@@ -27,7 +27,7 @@ from collections import deque
 import numpy as np
 import jax.numpy as jnp
 
-from benchmarks.common import save
+from benchmarks.common import run_main, save
 from repro.api import (DynamicGraphSystem, PartitionSection, StreamSection,
                        SystemConfig, TelemetrySection, XdgpAdaptive,
                        empty_graph)
@@ -162,4 +162,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    main()
+    run_main(main)

@@ -5,7 +5,7 @@ import dataclasses
 import json
 import os
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
@@ -13,6 +13,15 @@ from repro.core.vertex_program import CostModel
 from repro.obs.manifest import run_manifest
 
 RESULTS_DIR = os.environ.get("REPRO_RESULTS", "results")
+
+
+def run_main(main: Callable[[], Any]) -> Any:
+    """A benchmark script's entry point: run ``main`` with JAX's persistent
+    compilation cache on (``repro.compat.enable_compile_cache``), so runs
+    of the same shapes compile once."""
+    from repro.compat import enable_compile_cache
+    enable_compile_cache()
+    return main()
 
 
 def save(name: str, payload: Any, *, config: Any = None) -> str:

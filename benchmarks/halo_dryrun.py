@@ -1,5 +1,3 @@
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 """§Perf hillclimb cell 3 (paper-representative): gin-tu × ogb_products with
 the xDGP halo-exchange engine instead of GSPMD global gathers.
 
@@ -13,7 +11,10 @@ Variants lowered on the single-pod mesh (256 devices ≡ 256 partitions):
 Halo widths come from results/boundary_fractions.json (measured on a
 250k-node Chung–Lu proxy at k=256 — methodology in EXPERIMENTS.md §Perf).
 
-  PYTHONPATH=src python -m benchmarks.halo_dryrun
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m benchmarks.halo_dryrun
+
+The mesh is 256 of 512 fake CPU devices, which ``main`` asks for before JAX
+initialises.
 """
 import json
 
@@ -21,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compat import request_host_devices
 from repro.core.halo_gnn import abstract_dist_graph, gin_halo_loss
 from repro.launch.dryrun import parse_collective_bytes
 from repro.models.gnn import GINConfig, gin_init
@@ -87,6 +89,9 @@ def main() -> None:
       → 0.45; the paper's 100M-node biomedical FEM at k=256 (391k-node
       blocks) → 0.13.
     """
+    if not request_host_devices(512):
+        raise SystemExit("the dry run compiles for fake CPU devices; run it "
+                         "with JAX_PLATFORMS=cpu")
     P = 256
     cfg = GINConfig(n_layers=5, d_hidden=64, d_in=100, n_out=47,
                     readout="none", remat=True)

@@ -164,9 +164,10 @@ def _measured_correction(arch: str, kind: str, L: int) -> float:
 def calibrate(mesh_name: str = "single_pod_256") -> None:
     """Measure per-(arch, kind) scan-correction factors by differencing a
     2-layer and 4-layer lowering of the same cell on the production mesh."""
-    import os as _os
-    _os.environ.setdefault("XLA_FLAGS",
-                           "--xla_force_host_platform_device_count=512")
+    from repro.compat import request_host_devices
+    if not request_host_devices(512):
+        raise SystemExit("calibration compiles for 512 fake CPU devices; "
+                         "run it with JAX_PLATFORMS=cpu")
     import dataclasses as dc
     import jax
     from repro.configs import registry

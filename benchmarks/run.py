@@ -15,7 +15,7 @@ from benchmarks import (bench_elastic, bench_fig1_dynamic_cuts,
                         bench_fig2_s_sweep, bench_fig5_initial_partitioning,
                         bench_fig6_convergence, bench_fig7_dynamic_adaptation,
                         bench_usecase_comm_volume)
-from benchmarks.common import save
+from benchmarks.common import run_main, save
 
 BENCHES = {
     "fig1": bench_fig1_dynamic_cuts,
@@ -63,4 +63,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    main()
+    run_main(main)

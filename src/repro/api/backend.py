@@ -429,27 +429,28 @@ class ShardedBackend:
         (compiles + reps) is visible as an ``obs/comm_probe`` span.
         """
         mesh, dg, axis = self._mesh, self._dg, self.cluster.axis
-        from repro.compat import shard_map
         spec_n = jax.sharding.PartitionSpec(axis)
         dg_specs = DistGraph(*([spec_n] * 8))
-        rep = jax.sharding.PartitionSpec()
         P, n_blk = dg.num_devices, dg.block_size
 
+        # each device returns its own gathered copy (out_specs=spec_n): the
+        # same collective as the real step, with no replication for
+        # shard_map to prove, and results that are discarded anyway
         @jax.jit
         def halo_probe(flat):
-            f = shard_map(
+            f = jax.shard_map(
                 lambda lf, dgl: jax.lax.all_gather(
                     jnp.where(dgl.boundary_ok[0], lf[dgl.boundary[0]], 0),
                     axis, tiled=True),
-                mesh=mesh, in_specs=(spec_n, dg_specs), out_specs=rep)
+                mesh=mesh, in_specs=(spec_n, dg_specs), out_specs=spec_n)
             return f(flat, dg)
 
         @jax.jit
         def quota_probe(keys):
-            f = shard_map(
+            f = jax.shard_map(
                 lambda kb: jnp.sort(jax.lax.all_gather(kb, axis,
                                                        tiled=True)),
-                mesh=mesh, in_specs=(spec_n,), out_specs=rep)
+                mesh=mesh, in_specs=(spec_n,), out_specs=spec_n)
             return f(keys)
 
         @jax.jit
@@ -457,8 +458,8 @@ class ShardedBackend:
             # dispatch floor: a do-nothing shard_map of the same shape —
             # subtracted so the probes report collective cost, not the
             # per-dispatch overhead every tiny jit pays
-            f = shard_map(lambda xb: xb + 1, mesh=mesh, in_specs=(spec_n,),
-                          out_specs=spec_n)
+            f = jax.shard_map(lambda xb: xb + 1, mesh=mesh,
+                              in_specs=(spec_n,), out_specs=spec_n)
             return f(x)
 
         def best_of(fn, *a, reps: int = 3) -> float:

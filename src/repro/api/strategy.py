@@ -197,6 +197,26 @@ class StrategyBase:
     name = "base"
     adapts = False                 # True → adapt/converge run migrations
     cluster_native = False         # True → sharded backend may take over adapt
+    last_plan: Optional[Dict[str, Any]] = None   # describe_plan of the last
+                                   # batch-mode scoring plan (None = unfused)
+
+    def _plan(self, graph: Graph, backend: str):
+        """Pre-pack the adjacency for the fused scorer (batch modes only).
+
+        Streaming ``adapt`` passes ``plan=None`` — the packing-free flat
+        plan — because the graph changes every superstep and a host-side
+        repack per superstep would cost more than it saves. The batch
+        drivers (``converge``/``adapt_rounds``) run many iterations over a
+        fixed graph, so one pack amortises across all of them. The plan's
+        kind is kept in ``last_plan`` so the session can report it.
+        """
+        if backend != "pallas":
+            self.last_plan = None
+            return None
+        from repro.kernels.migration_kernels import build_plan, describe_plan
+        plan = build_plan(graph)
+        self.last_plan = describe_plan(plan)
+        return plan
 
     def init(self, graph: Graph, k: int) -> jax.Array:
         return hash_partition(graph, k)
@@ -345,20 +365,6 @@ class XdgpAdaptive(OnlineFennel):
             return ctx.assignment
         return super().place(delta, ctx)
 
-    def _plan(self, graph: Graph, backend: str):
-        """Pre-pack the adjacency for the fused scorer (batch modes only).
-
-        Streaming ``adapt`` passes ``plan=None`` — the packing-free flat
-        plan — because the graph changes every superstep and a host-side
-        repack per superstep would cost more than it saves. The batch
-        drivers (``converge``/``adapt_rounds``) run many iterations over a
-        fixed graph, so one pack amortises across all of them.
-        """
-        if backend != "pallas":
-            return None
-        from repro.kernels.migration_kernels import build_plan
-        return build_plan(graph)
-
     def adapt(self, graph: Graph, state: PartitionState,
               ctx: StrategyContext) -> PartitionState:
         backend = resolve_backend(ctx.backend)
@@ -390,15 +396,6 @@ class XdgpAdaptive(OnlineFennel):
                             backend=backend, plan=self._plan(graph, backend))
 
 
-def _maybe_plan(graph: Graph, backend: str):
-    """Pre-pack the adjacency for the fused scorer when the pallas backend
-    is selected (batch drivers only — see ``XdgpAdaptive._plan``)."""
-    if backend != "pallas":
-        return None
-    from repro.kernels.migration_kernels import build_plan
-    return build_plan(graph)
-
-
 @register_strategy("spinner", "lpa")
 class Spinner(StrategyBase):
     """Spinner-style balanced label propagation (arXiv 1404.3861).
@@ -420,7 +417,7 @@ class Spinner(StrategyBase):
         self._adapt_cache: Dict[Tuple[float, float, int, str], Callable] = {}
 
     def _step_fn(self, graph: Graph, ctx: StrategyContext, backend: str):
-        plan = _maybe_plan(graph, backend)
+        plan = self._plan(graph, backend)
         return lambda st: spinner_step(st, graph, plan,
                                        balance_weight=self.balance_weight,
                                        s=ctx.s, backend=backend)
@@ -472,7 +469,7 @@ class Sdp(OnlineFennel):
         self._adapt_cache: Dict[Tuple[float, int, str], Callable] = {}
 
     def _step_fn(self, graph: Graph, ctx: StrategyContext, backend: str):
-        plan = _maybe_plan(graph, backend)
+        plan = self._plan(graph, backend)
         return lambda st: sdp_refine_step(st, graph, plan, s=ctx.s,
                                           backend=backend)
 

@@ -281,6 +281,16 @@ class DynamicGraphSystem:
         return float(imbalance_of(self.tracker))
 
     @property
+    def scoring_plan(self) -> Optional[Dict]:
+        """Kind and packed shape of the plan the last batch ``adapt()`` /
+        ``converge()`` scored over (``"bsr"`` tiles when they fit the
+        device-memory budget, else ``"flat"``); None for the unfused
+        reference path, before the first batch call, or for a strategy
+        that only meets the ``PartitionStrategy`` protocol (which does not
+        declare ``last_plan``; cf. ``cluster_native`` in ``api/backend``)."""
+        return getattr(self.strategy, "last_plan", None)
+
+    @property
     def backlog(self) -> Tuple[int, int]:
         """Deferred ingest work: (queued adds, queued dels) still sitting in
         the stream buffer past a_cap/d_cap — the capacity-backpressure signal
@@ -765,6 +775,7 @@ class DynamicGraphSystem:
             "strategy": self.strategy.name,
             "backend": self.backend.name,
             "cluster": self.backend.device_stats(),
+            "scoring_plan": self.scoring_plan,
             "k": self.config.partition.k,
             "supersteps": self._superstep,
             "now": self._now,

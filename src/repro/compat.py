@@ -1,58 +1,70 @@
-"""Version- and platform-compat shims over moving JAX APIs.
+"""Platform choices the rest of the repo makes in one place.
 
-The repo targets the newest public API surface (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``) but must run on whatever JAX the
-container bakes in. Everything that touches these APIs goes through here so
-a version bump is a one-file change.
-
-* ``make_mesh(shape, axes)`` — ``jax.sharding.AxisType`` appeared after
-  0.4.x; older JAX builds the same (fully ``Auto``) mesh without the kwarg.
-* ``shard_map(...)`` — ``jax.shard_map`` graduated from
-  ``jax.experimental.shard_map``; the experimental one additionally needs
-  ``check_rep=False`` for programs that thread PRNG keys through collectives.
+* ``make_mesh(shape, axes)`` — ``jax.make_mesh`` with ``Auto`` axis types
+  (JAX's default is ``Explicit``, which the shard_map engines do not use).
 * ``resolve_backend`` / ``pallas_executor`` — the one place that decides how
   the fused migration kernels execute on this host (DESIGN.md §9): native
   Mosaic on TPU, the bit-exact pure-jax oracle on CPU, or the Pallas
-  interpreter when CI forces it.
+  interpreter when a test forces it.
+* ``enable_compile_cache`` — JAX's persistent compilation cache for entry
+  points (``chip_smoke.py``, the benchmarks); never called on import.
+* ``request_host_devices`` — fake CPU devices for dry runs and
+  multi-device rehearsals, asked for only where JAX may run on the CPU.
 """
 from __future__ import annotations
 
 import os
+from pathlib import Path
 from typing import Sequence, Tuple
 
 import jax
 
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _NEEDS_CHECK_REP = False
-else:  # pre-graduation JAX
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _NEEDS_CHECK_REP = True
+_CHECKOUT = Path(__file__).resolve().parents[2]
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Sequence[str]) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with explicit-Auto axis types where supported."""
-    if _AXIS_TYPE is not None:
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(_AXIS_TYPE.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """Uniform shard_map entry point across JAX versions."""
-    if _NEEDS_CHECK_REP:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+def request_host_devices(count: int) -> bool:
+    """Ask the CPU backend for ``count`` devices by appending
+    ``--xla_force_host_platform_device_count`` to ``XLA_FLAGS``.
+
+    The flag sizes only JAX's host platform, and JAX picks its platform
+    only when it initialises (falling back to the CPU where no accelerator
+    answers), so the flag goes in wherever the CPU may be picked: always,
+    unless a count is already set or ``JAX_PLATFORMS`` leaves the CPU out.
+    Call it before JAX initialises its backends (the flag is read once,
+    then), and check the platform JAX picked afterwards. Returns whether
+    the flag is in place.
+    """
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" in flags:
+        return True
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "cpu" not in platforms.split(","):
+        return False
+    os.environ["XLA_FLAGS"] = (
+        f"{flags} --xla_force_host_platform_device_count={count}".strip())
+    return True
 
 
-def axis_size(axis_name: str):
-    """``jax.lax.axis_size`` fallback: psum of a unit is folded statically."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it and
+    nothing is changed. Otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache``: the directory is part of the cache key, so a
+    path that moved between runs would never hit.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(_CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 # ---------------------------------------------------------------------------

@@ -39,7 +39,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import axis_size, shard_map
 from repro.graph.structure import Graph
 
 # Trace-time counters: bumped inside jitted function *bodies*, so they count
@@ -303,7 +302,7 @@ def make_distributed_aggregate(mesh: jax.sharding.Mesh, dg: DistGraph):
 
     @jax.jit
     def agg_fn(features: jax.Array) -> jax.Array:
-        f = shard_map(
+        f = jax.shard_map(
             lambda lf, dgl: superstep_shard(lf, dgl, halo),
             mesh=mesh,
             in_specs=(jax.sharding.PartitionSpec(AXIS, None), dg_specs),
@@ -368,7 +367,7 @@ def migrate_step_shard(assignment_blk: jax.Array, pending_blk: jax.Array,
     # deferred physical relocation a partition's vertices can span several
     # storage blocks, so the per-block quota must bound the TOTAL influx:
     # free // P guarantees sum over blocks ≤ free for any label placement.
-    n_blocks = axis_size(AXIS)
+    n_blocks = jax.lax.axis_size(AXIS)
     quota = free // jnp.maximum(n_blocks, 1)
     # QUOTA: local ranking of this block's movers per destination
     tgt_safe = jnp.clip(target, 0, k - 1)
@@ -397,7 +396,7 @@ def make_distributed_migrator(mesh: jax.sharding.Mesh, dg: DistGraph, k: int,
     @jax.jit
     def step(assignment: jax.Array, pending: jax.Array, rng: jax.Array,
              capacity: jax.Array):
-        f = shard_map(
+        f = jax.shard_map(
             partial(migrate_step_shard, k=k, halo_size=halo, s=s),
             mesh=mesh,
             in_specs=(spec_n, spec_n, jax.sharding.PartitionSpec(), dg_specs,
@@ -599,7 +598,7 @@ def make_cluster_step(mesh: jax.sharding.Mesh, *, k: int, n_cap: int,
         else:
             noise_blk = jnp.zeros((orig.shape[0], k), jnp.float32)
         gate_blk = jax.random.bernoulli(sub, p=s, shape=(n_cap,))[orig_safe]
-        f = shard_map(
+        f = jax.shard_map(
             partial(cluster_migrate_shard, k=k, halo_size=halo, n_cap=n_cap,
                     tie_break=tie_break, axis=axis, key_dtype=key_dtype),
             mesh=mesh,
@@ -632,6 +631,7 @@ def make_cluster_step(mesh: jax.sharding.Mesh, *, k: int, n_cap: int,
                               replicated)
         return step(*args, float(s), dg, blk_live, orig, ng_safe, slot_live)
 
+    step_on_mesh.jitted = step      # the program itself, for AOT lowering
     return step_on_mesh
 
 

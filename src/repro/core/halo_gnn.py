@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
 from repro.core.distributed import AXIS, DistGraph, _halo_exchange
 from repro.models.gnn import GINConfig, _layernorm, _linear, _mlp2
 
@@ -107,7 +106,7 @@ def gin_halo_forward(params: Params, dg: DistGraph, feats: jax.Array,
             h = step(lp, h)
         return _mlp2(params["decode"], h)
 
-    return shard_map(body, mesh=mesh, in_specs=(spec_n, dg_specs),
+    return jax.shard_map(body, mesh=mesh, in_specs=(spec_n, dg_specs),
                      out_specs=spec_n)(feats, dg)
 
 

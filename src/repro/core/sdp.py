@@ -86,7 +86,7 @@ def sdp_refine_step(state: PartitionState, graph: Graph, plan=None, *,
 
     free = jnp.maximum(state.capacity - occ, 0)
     tgt = jnp.clip(target, 0, k - 1)
-    rank = _rank_within_group(tgt, willing)
+    rank = _rank_within_group(tgt, willing, k)
     admitted = willing & (rank < free[tgt])
     moved = jnp.sum(admitted).astype(jnp.int32)
 

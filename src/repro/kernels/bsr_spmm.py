@@ -22,13 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_TPU = True
-except Exception:                                        # pragma: no cover
-    pltpu = None
-    _HAS_TPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(row_ptr_ref, cols_ref, a_ref, x_ref, o_ref, *, max_per_row: int):

@@ -44,19 +44,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro import compat
-from repro.graph.bsr import graph_to_bsr
 from repro.graph.structure import Graph
 from repro.kernels import ref
 from repro.kernels.bsr_spmm import max_tiles_per_row
+from repro.scale.chunked_bsr import MemoryBudgetError, graph_to_bsr_chunked
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS_TPU = True
-except Exception:                                        # pragma: no cover
-    pltpu = None
-    _HAS_PALLAS_TPU = False
 
 
 # ---------------------------------------------------------------------------
@@ -77,8 +72,9 @@ class MigrationPlan:
       "ell"  — padded neighbour lists ``(n_cap, deg_cap)``; turns the
                histogram into dense gather+compare (the CPU winner on
                low-skew graphs like the paper's FEM meshes).
-      "bsr"  — the BSR tiles from ``graph_to_bsr``; what the Pallas kernel
-               streams through the MXU (``native``/``interpret``).
+      "bsr"  — the BSR tiles from ``graph_to_bsr_chunked``; what the Pallas
+               kernel streams through the MXU (``native``/``interpret``),
+               built only when the pack fits the device-memory budget.
     """
 
     kind: str
@@ -96,6 +92,30 @@ jax.tree_util.register_dataclass(
 
 FLAT_PLAN = MigrationPlan(kind="flat")
 
+# Share of the default device's memory a packed BSR plan may take; the rest
+# holds the graph, the session state and the scorer's temporaries.
+PLAN_MEMORY_FRACTION = 0.25
+# Budget where the device reports no memory limit (the CPU backend).
+_UNREPORTED_PLAN_BUDGET = 2 << 30
+
+
+def plan_memory_budget() -> int:
+    """Bytes a BSR plan may take on the default device."""
+    limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+    return int(limit * PLAN_MEMORY_FRACTION) if limit else \
+        _UNREPORTED_PLAN_BUDGET
+
+
+def describe_plan(plan: MigrationPlan) -> dict:
+    """The plan's kind and packed shape, for snapshots and run logs."""
+    if plan.kind == "bsr":
+        nnzb, blk, _ = plan.blocks.shape
+        return {"kind": "bsr", "blk": int(blk), "nnzb": int(nnzb),
+                "max_per_row": int(plan.max_per_row)}
+    if plan.kind == "ell":
+        return {"kind": "ell", "deg_cap": int(plan.nbrs.shape[1])}
+    return {"kind": plan.kind}
+
 
 def build_plan(graph: Graph, *, executor: Optional[str] = None,
                blk: int = 64, ell_max_overhead: float = 4.0) -> MigrationPlan:
@@ -103,13 +123,19 @@ def build_plan(graph: Graph, *, executor: Optional[str] = None,
 
     ``executor`` (default: :func:`repro.compat.pallas_executor`) picks the
     representation: BSR tiles for the Pallas executors, ELL neighbour lists
-    for the pure-jax oracle — unless the degree skew would pad ELL beyond
-    ``ell_max_overhead``× the edge count, in which case the plan degrades
-    to "flat" (no packing, still fused).
+    for the pure-jax oracle. Either degrades to the packing-free "flat"
+    plan (still fused) when packing would not pay: a BSR pack larger than
+    :func:`plan_memory_budget` is refused before it is allocated, and
+    degree skew that would pad ELL beyond
+    ``ell_max_overhead``× the edge count is not packed.
     """
     executor = compat.pallas_executor() if executor is None else executor
     if executor in ("native", "interpret"):
-        bsr = graph_to_bsr(graph, blk=blk)
+        try:
+            bsr = graph_to_bsr_chunked(graph, blk=blk,
+                                       memory_budget=plan_memory_budget())
+        except MemoryBudgetError:
+            return FLAT_PLAN                  # the tiles would not fit
         return MigrationPlan(
             kind="bsr", blocks=bsr.blocks, block_cols=bsr.block_cols,
             row_ptr=bsr.row_ptr,
@@ -167,10 +193,15 @@ def _counts_ell(nbrs: jax.Array, assignment: jax.Array, k: int) -> jax.Array:
 def _fused_kernel(row_ptr_ref, cols_ref, a_ref, lab_ref, cur_ref, mask_ref,
                   noise_ref, gate_ref, counts_ref, target_ref, willing_ref,
                   gain_ref, *, k: int, max_per_row: int, tie_break: str):
+    # vertices run along lanes: per-vertex blocks are (1, blk) rows and the
+    # histogram is accumulated transposed, (k, blk), so no operand needs a
+    # lane->sublane relayout
     i = pl.program_id(0)
     j = pl.program_id(1)
     start = row_ptr_ref[i]
     end = row_ptr_ref[i + 1]
+    blk = a_ref.shape[-1]
+    iota_k = jax.lax.broadcasted_iota(jnp.int32, (k, blk), 0)
 
     @pl.when(j == 0)
     def _init():
@@ -178,35 +209,34 @@ def _fused_kernel(row_ptr_ref, cols_ref, a_ref, lab_ref, cur_ref, mask_ref,
 
     @pl.when(start + j < end)
     def _accum():
-        a = a_ref[0]                                      # (blk, blk)
-        lab = lab_ref[0]                                  # (blk,) column labels
-        blk = a.shape[0]
-        iota_k = jax.lax.broadcasted_iota(jnp.int32, (blk, k), 1)
-        onehot = (lab[:, None] == iota_k).astype(jnp.float32)
-        counts_ref[0] += jax.lax.dot(a, onehot,
-                                     preferred_element_type=jnp.float32)
+        a = a_ref[0]                                      # (blk, blk) [row, col]
+        onehot_t = (lab_ref[0] == iota_k).astype(jnp.float32)  # (k, blk) [j, col]
+        # counts^T[j, row] += sum_col onehot_t[j, col] * a[row, col]
+        counts_ref[0] += jax.lax.dot_general(
+            onehot_t, a, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     @pl.when(j == max_per_row - 1)
     def _select():
-        c = counts_ref[0]                                 # (blk, k) exact ints
-        cur = cur_ref[0]
+        c = counts_ref[0]                                 # (k, blk) exact ints
+        cur = cur_ref[0]                                  # (1, blk)
         mask = mask_ref[0] != 0
-        iota_k = jax.lax.broadcasted_iota(jnp.int32, c.shape, 1)
+        fiota = iota_k.astype(jnp.float32)
         cur_cl = jnp.clip(cur, 0, k - 1)
-        cur_count = jnp.sum(jnp.where(iota_k == cur_cl[:, None], c, 0.0),
-                            axis=1)
-        best = jnp.max(c, axis=1)
+        cur_count = jnp.sum(jnp.where(iota_k == cur_cl, c, 0.0), axis=0,
+                            keepdims=True)
+        best = jnp.max(c, axis=0, keepdims=True)
         isolated = (best == 0.0) | ~mask
         if tie_break == "stay":
-            first = jnp.min(jnp.where(c == best[:, None], iota_k, k),
-                            axis=1).astype(jnp.int32)
+            first = jnp.min(jnp.where(c == best, fiota, float(k)), axis=0,
+                            keepdims=True).astype(jnp.int32)
             stay = (cur_count >= best) | isolated
             tgt = jnp.where(stay, cur_cl, first)
         else:
             score = c + noise_ref[0]
-            smax = jnp.max(score, axis=1)
-            first = jnp.min(jnp.where(score == smax[:, None], iota_k, k),
-                            axis=1).astype(jnp.int32)
+            smax = jnp.max(score, axis=0, keepdims=True)
+            first = jnp.min(jnp.where(score == smax, fiota, float(k)), axis=0,
+                            keepdims=True).astype(jnp.int32)
             tgt = jnp.where(isolated, cur_cl, first)
         willing = (tgt != cur) & mask & (gate_ref[0] != 0)
         target_ref[0] = tgt
@@ -230,63 +260,57 @@ def pallas_score_select(blocks: jax.Array, block_cols: jax.Array,
     ``n_pad`` rows; callers slice back to ``n_cap``. Padding tiles
     (``block_cols == -1``) are never visited: ``row_ptr`` only addresses
     the packed prefix, and the ``start + j < end`` guard masks the rest.
+
+    Per-vertex operands are laid out ``(n_blocks, rows, blk)`` with the
+    vertex on the last (lane) axis, so every block's last two dimensions
+    are the array's own — the TPU tiling rule for blocks that are not
+    multiples of (8, 128).
     """
-    if pltpu is None:                                     # pragma: no cover
-        raise RuntimeError("pallas TPU frontend unavailable; use the 'jax' "
-                           "executor (repro.compat.pallas_executor)")
     nnzb, blk, _ = blocks.shape
     n_blocks = row_ptr.shape[0] - 1
-    lab_b = assignment.reshape(n_blocks, blk)
-    cur_b = lab_b
-    mask_b = node_mask.astype(jnp.int32).reshape(n_blocks, blk)
-    noise_b = noise.reshape(n_blocks, blk, k)
-    gate_b = gate.astype(jnp.int32).reshape(n_blocks, blk)
+    lab_b = assignment.reshape(n_blocks, 1, blk)
+    mask_b = node_mask.astype(jnp.int32).reshape(n_blocks, 1, blk)
+    noise_b = noise.reshape(n_blocks, blk, k).transpose(0, 2, 1)
+    gate_b = gate.astype(jnp.int32).reshape(n_blocks, 1, blk)
 
     def a_index(i, j, row_ptr_s, cols_s):
         return (jnp.clip(row_ptr_s[i] + j, 0, nnzb - 1), 0, 0)
 
     def col_index(i, j, row_ptr_s, cols_s):
         idx = jnp.clip(row_ptr_s[i] + j, 0, nnzb - 1)
-        return (jnp.clip(cols_s[idx], 0, n_blocks - 1), 0)
+        return (jnp.clip(cols_s[idx], 0, n_blocks - 1), 0, 0)
 
     def row_index(i, j, row_ptr_s, cols_s):
-        return (i, 0)
-
-    def row_index3(i, j, row_ptr_s, cols_s):
         return (i, 0, 0)
 
+    row = pl.BlockSpec((1, 1, blk), row_index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_blocks, max_per_row),
         in_specs=[
             pl.BlockSpec((1, blk, blk), a_index),
-            pl.BlockSpec((1, blk), col_index),
-            pl.BlockSpec((1, blk), row_index),
-            pl.BlockSpec((1, blk), row_index),
-            pl.BlockSpec((1, blk, k), row_index3),
-            pl.BlockSpec((1, blk), row_index),
+            pl.BlockSpec((1, 1, blk), col_index),
+            row,
+            row,
+            pl.BlockSpec((1, k, blk), row_index),
+            row,
         ],
-        out_specs=[
-            pl.BlockSpec((1, blk, k), row_index3),
-            pl.BlockSpec((1, blk), row_index),
-            pl.BlockSpec((1, blk), row_index),
-            pl.BlockSpec((1, blk), row_index),
-        ],
+        out_specs=[pl.BlockSpec((1, k, blk), row_index), row, row, row],
     )
     counts, target, willing, gain = pl.pallas_call(
         functools.partial(_fused_kernel, k=k, max_per_row=max_per_row,
                           tie_break=tie_break),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((n_blocks, blk, k), jnp.float32),
-            jax.ShapeDtypeStruct((n_blocks, blk), jnp.int32),
-            jax.ShapeDtypeStruct((n_blocks, blk), jnp.int32),
-            jax.ShapeDtypeStruct((n_blocks, blk), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks, k, blk), jnp.float32),
+            jax.ShapeDtypeStruct((n_blocks, 1, blk), jnp.int32),
+            jax.ShapeDtypeStruct((n_blocks, 1, blk), jnp.int32),
+            jax.ShapeDtypeStruct((n_blocks, 1, blk), jnp.float32),
         ],
         interpret=interpret,
-    )(row_ptr, block_cols, blocks, lab_b, cur_b, mask_b, noise_b, gate_b)
+    )(row_ptr, block_cols, blocks, lab_b, lab_b, mask_b, noise_b, gate_b)
     n_pad = n_blocks * blk
-    return (counts.reshape(n_pad, k), target.reshape(n_pad),
+    return (counts.transpose(0, 2, 1).reshape(n_pad, k), target.reshape(n_pad),
             willing.reshape(n_pad), gain.reshape(n_pad))
 
 

@@ -1,12 +1,11 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: jax locks the device count on first init.
-
 """Multi-pod dry-run: lower + compile every (arch × shape) cell on the
 production meshes and record memory/cost/collective analyses.
 
-  PYTHONPATH=src python -m repro.launch.dryrun [--arch A] [--shape S]
-      [--mesh single|multi|both] [--out results/]
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.dryrun \
+      [--arch A] [--shape S] [--mesh single|multi|both] [--out results/]
+
+The meshes are 512 fake CPU devices, which ``main`` asks for before JAX
+initialises.
 
 Proves (assignment deliverable (e)): the distribution config is coherent —
 .lower().compile() succeeds for the 16×16 (256-chip) single-pod mesh AND the
@@ -15,6 +14,7 @@ fits; cost_analysis + HLO collective parsing feed §Roofline.
 """
 import argparse
 import json
+import os
 import re
 import time
 import traceback
@@ -23,6 +23,7 @@ from typing import Any, Dict, Optional
 import jax
 import numpy as np
 
+from repro.compat import request_host_devices
 from repro.configs import registry
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import build_cell
@@ -145,6 +146,9 @@ def main() -> None:
     ap.add_argument("--force", action="store_true",
                     help="recompute cached cells")
     args = ap.parse_args()
+    if not request_host_devices(512):
+        raise SystemExit("the dry run compiles for 512 fake CPU devices; "
+                         "run it with JAX_PLATFORMS=cpu")
     os.makedirs(args.out, exist_ok=True)
 
     meshes = []

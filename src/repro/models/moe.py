@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import axis_size, shard_map
 from repro.runtime.sharding import constrain
 
 Params = Dict[str, Any]
@@ -147,7 +146,7 @@ def _local_dispatch_ffn(w_gate, w_up, w_down, router, x_loc, cfg: MoEConfig,
     """
     t_loc, d = x_loc.shape
     e = cfg.n_experts
-    m = axis_size(model_axis)
+    m = jax.lax.axis_size(model_axis)
     e_loc = e // m
     cap = max(1, int(math.ceil(t_loc * cfg.top_k * cfg.capacity_factor / e)))
 
@@ -229,7 +228,7 @@ def _moe_shard_map(params: Params, x: jax.Array, cfg: MoEConfig,
                                      all_axes=all_axes)
         return y.reshape(bb, ss, dd), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P("model", None, fsdp), P("model", None, fsdp),
                   P("model", fsdp, None), P(dp, "model", None)),

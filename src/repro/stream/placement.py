@@ -104,13 +104,13 @@ def place_delta(delta: GraphDelta, node_mask: jax.Array, assignment: jax.Array,
     # the residue lands in the last partition — there is nowhere legal left.
     free = jnp.maximum(capacity - occupancy, 0)
     chosen = jnp.clip(labels, 0, k - 1)
-    rank = _rank_within_group(chosen, is_new)
+    rank = _rank_within_group(chosen, is_new, k)
     over = is_new & (rank >= free[chosen])
     adm_seg = jnp.where(is_new & ~over, chosen, k)
     admitted = jax.ops.segment_sum(jnp.ones_like(chosen), adm_seg,
                                    num_segments=k + 1)[:k]
     room_left = jnp.maximum(free - admitted, 0)
-    spill_rank = _rank_within_group(jnp.zeros_like(chosen), over)
+    spill_rank = _rank_within_group(jnp.zeros_like(chosen), over, 1)
     spill_to = jnp.searchsorted(jnp.cumsum(room_left), spill_rank, side="right")
     spill_to = jnp.clip(spill_to, 0, k - 1).astype(jnp.int32)
     labels = jnp.where(over, spill_to, labels)

@@ -21,6 +21,8 @@ try:
 except ImportError:                                     # pragma: no cover
     from _hypothesis_fallback import given, settings, st
 
+from repro.api import DynamicGraphSystem, SystemConfig
+from repro.api.config import ComputeSection, PartitionSection
 from repro.core import initial_partition, make_state, occupancy
 from repro.core.migration import (_rank_within_group, _rank_within_group_fast,
                                   migrate_step, neighbour_partition_counts)
@@ -28,7 +30,7 @@ from repro.core.repartitioner import adapt_jit, run_to_convergence
 from repro.graph import generators
 from repro.graph.bsr import graph_to_bsr
 from repro.graph.structure import Graph, from_edges
-from repro.kernels import ref
+from repro.kernels import migration_kernels, ref
 from repro.kernels.bsr_spmm import max_tiles_per_row
 from repro.kernels.migration_kernels import (MigrationPlan, build_plan,
                                              label_histogram,
@@ -208,6 +210,36 @@ def test_driver_parity_adapt_and_converge():
     assert ha.cut_ratio == hb.cut_ratio
 
 
+@pytest.mark.parametrize("kind, budget, want", [
+    ("fem", None, "bsr"),           # 6^3 mesh: one 16 KiB tile row per block
+    ("plc", 4096, "flat"),          # a single 64x64 tile is over 4 KiB
+])
+def test_batch_plan_respects_device_budget(monkeypatch, kind, budget, want):
+    """On the Pallas executors a BSR pack over the device-memory budget is
+    refused before it is allocated and the batch drivers score over the
+    flat plan instead. The session reports the kind, and the assignments
+    equal the reference path's either way."""
+    monkeypatch.setenv("REPRO_PALLAS_EXECUTOR", "interpret")
+    if budget is not None:
+        monkeypatch.setattr(migration_kernels, "plan_memory_budget",
+                            lambda: budget)
+    g = _random_graph(216, 1, kind)
+    labels = {}
+    for backend in ("pallas", "ref"):
+        cfg = SystemConfig(partition=PartitionSection(strategy="xdgp", k=4),
+                           compute=ComputeSection(backend=backend), seed=2)
+        system = DynamicGraphSystem(g, cfg)
+        system.adapt(3)
+        labels[backend] = np.asarray(system.labels)
+        plan = system.scoring_plan
+        if backend == "pallas":
+            assert plan["kind"] == want
+            assert system.snapshot()["scoring_plan"] == plan
+        else:
+            assert plan is None
+    np.testing.assert_array_equal(labels["pallas"], labels["ref"])
+
+
 # ---------------------------------------------------------------------------
 # capacity invariant + full partitions under the fused path
 # ---------------------------------------------------------------------------
@@ -260,7 +292,14 @@ def test_rank_within_group_fast_matches_stable(n, num_groups, seed, density):
     rng = np.random.default_rng(seed)
     group = jnp.asarray(rng.integers(0, num_groups, n).astype(np.int32))
     active = jnp.asarray(rng.random(n) < density)
-    slow = np.asarray(_rank_within_group(group, active))
+    slow = np.asarray(_rank_within_group(group, active, num_groups))
     fast = np.asarray(_rank_within_group_fast(group, active,
                                               num_groups=num_groups))
     np.testing.assert_array_equal(slow, fast)
+    # both against the definition: id-order count of earlier active members
+    g, a = np.asarray(group), np.asarray(active)
+    want = np.zeros(n, np.int32)
+    for j in np.unique(g[a]):
+        idx = np.flatnonzero(a & (g == j))
+        want[idx] = np.arange(idx.size)
+    np.testing.assert_array_equal(slow, want)
