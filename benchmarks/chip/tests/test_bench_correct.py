@@ -1,0 +1,102 @@
+"""The comparison that decides ``correct``, on tiny cells on the CPU.
+
+Each cell comes out correct as it is; its control (the reference in
+bfloat16 in the session's place) and every fault the cell can have,
+planted under the timed path, come out not correct.
+"""
+import dataclasses
+
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chip import adapt_cell, stream_cell
+from chip.tests.cpu import run_tiny, tiny_cell
+
+STREAM = ("g500-s18-sat", "g500-s18-paced")
+
+
+@pytest.mark.parametrize("workload", STREAM + ("fem64-adapt",))
+def test_cell_is_correct(workload):
+    out = run_tiny(workload)
+    assert out.correct, out.checks
+    assert out.failed == 0 and out.attempted > 0
+
+
+@pytest.mark.parametrize("workload", STREAM + ("fem64-adapt",))
+def test_control_is_refused(workload):
+    out = run_tiny(workload)
+    runner = adapt_cell if workload == "fem64-adapt" else stream_cell
+    replay = out.run["replay"]
+    checks = (runner.control(tiny_cell(workload), replay)
+              if runner is adapt_cell else
+              runner.control(tiny_cell(workload), 7, replay))
+    assert any(c["value"] > c["limit"] for c in checks.values()), checks
+
+
+def _state_unchanged(monkeypatch):
+    from repro.api.strategy import XdgpAdaptive
+    monkeypatch.setattr(XdgpAdaptive, "adapt",
+                        lambda self, graph, state, ctx: state)
+    real = XdgpAdaptive.adapt_rounds
+    monkeypatch.setattr(
+        XdgpAdaptive, "adapt_rounds",
+        lambda self, graph, state, iters, ctx:
+        (state, real(self, graph, state, 0, ctx)[1]))
+
+
+def _answer_altered(monkeypatch):
+    from repro.api.strategy import XdgpAdaptive
+    real_adapt, real_rounds = XdgpAdaptive.adapt, XdgpAdaptive.adapt_rounds
+
+    def flip(state):
+        lab = state.assignment
+        return dataclasses.replace(state, assignment=lab.at[3].set(
+            (lab[3] + 1) % state.k))
+
+    monkeypatch.setattr(XdgpAdaptive, "adapt",
+                        lambda self, g, st, ctx: flip(real_adapt(self, g, st,
+                                                                 ctx)))
+    monkeypatch.setattr(
+        XdgpAdaptive, "adapt_rounds",
+        lambda self, g, st, it, ctx: (lambda r: (flip(r[0]), r[1]))(
+            real_rounds(self, g, st, it, ctx)))
+
+
+def _half_batch(monkeypatch):
+    from repro.stream.ingest import WindowIngestor
+    real = WindowIngestor.ingest
+    monkeypatch.setattr(WindowIngestor, "ingest",
+                        lambda self, events, now: real(
+                            self, np.asarray(events)[::2], now))
+
+
+def _half_graph(monkeypatch):
+    """FEM: half of the edges left out of the scoring."""
+    import repro.core.repartitioner as rep
+    real = rep.migrate_step
+
+    def half(state, graph, plan=None, **kw):
+        mask = graph.edge_mask & (jnp.arange(graph.e_cap) % 2 == 0)
+        return real(state, dataclasses.replace(graph, edge_mask=mask), None,
+                    **kw)
+
+    monkeypatch.setattr(rep, "migrate_step", half)
+
+
+FAULTS = [("g500-s18-sat", _state_unchanged), ("g500-s18-sat", _half_batch),
+          ("g500-s18-sat", _answer_altered),
+          ("g500-s18-paced", _state_unchanged),
+          ("g500-s18-paced", _half_batch),
+          ("g500-s18-paced", _answer_altered),
+          ("fem64-adapt", _state_unchanged), ("fem64-adapt", _half_graph),
+          ("fem64-adapt", _answer_altered)]
+
+
+@pytest.mark.parametrize("workload,fault", FAULTS,
+                         ids=[f"{w}-{f.__name__.strip('_')}"
+                              for w, f in FAULTS])
+def test_fault_is_refused(workload, fault, monkeypatch):
+    fault(monkeypatch)
+    out = run_tiny(workload)
+    assert not out.correct, out.checks
