@@ -1,0 +1,8 @@
+"""Blocking device-to-host reads per profiled adaptation (the program's
+``host_syncs`` counter, one sample per ``adapt()``)."""
+
+
+def read(run):
+    counts = [e["value"] for e in run.get("spans", ())
+              if e.get("type") == "counter" and e["name"] == "host_syncs"]
+    return sum(counts) / len(counts) if counts else None

@@ -168,8 +168,7 @@ class LocalBackend:
         self.cluster = cluster if cluster is not None else ClusterSection()
 
     def adapt(self, strategy, graph, state, ctx):
-        with self.tracer.span("kernel/score_select",
-                              iters=ctx.adapt_iters) as sp:
+        with self.tracer.span("adapt", iters=ctx.adapt_iters) as sp:
             state = strategy.adapt(graph, state, ctx)
             sp.fence(state.assignment)
         return state
@@ -533,7 +532,7 @@ class ShardedBackend:
             max_iters=ctx.max_iters, tie_break=ctx.tie_break,
             rel_tol=ctx.rel_tol, record_history=ctx.record_history,
             step_fn=self._step_fn(graph, ctx, unshard_each=True,
-                                  state=state))
+                                  state=state), tracer=ctx.tracer)
         return state, hist
 
     def adapt_rounds(self, strategy, graph, state, iters, ctx):
@@ -543,7 +542,7 @@ class ShardedBackend:
         state, hist = _adapt_rounds(
             graph, state, iters, record_history=ctx.record_history,
             step_fn=self._step_fn(graph, ctx, unshard_each=True,
-                                  state=state))
+                                  state=state), tracer=ctx.tracer)
         return state, hist
 
     # -- telemetry ----------------------------------------------------------

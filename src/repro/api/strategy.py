@@ -71,6 +71,7 @@ from repro.core.restream import restream_state
 from repro.core.sdp import sdp_adapt_jit, sdp_refine_step
 from repro.core.spinner import spinner_adapt_jit, spinner_step
 from repro.graph.structure import Graph, GraphDelta, cut_ratio
+from repro.obs.trace import NULL_TRACER
 from repro.stream.placement import place_delta
 
 
@@ -81,7 +82,8 @@ class StrategyContext:
     The partitioning knobs mirror ``SystemConfig.partition``; the array
     fields are filled by the system per call. ``placed`` is the one
     out-parameter: a placement strategy sets it to the exact number of
-    vertices it placed.
+    vertices it placed. ``tracer`` is the session's (``repro.obs.trace``):
+    strategies open their spans on it and count their host reads with it.
     """
 
     k: int = 8
@@ -100,6 +102,7 @@ class StrategyContext:
     occupancy: Optional[jax.Array] = None    # (k,) live vertices per partition
     capacity: Optional[jax.Array] = None     # (k,) hard capacities
     rng: Optional[jax.Array] = None          # fresh subkey for this call
+    tracer: Any = NULL_TRACER
     # out-parameter
     placed: Optional[int] = None
 
@@ -200,7 +203,7 @@ class StrategyBase:
     last_plan: Optional[Dict[str, Any]] = None   # describe_plan of the last
                                    # batch-mode scoring plan (None = unfused)
 
-    def _plan(self, graph: Graph, backend: str):
+    def _plan(self, graph: Graph, ctx: StrategyContext, backend: str):
         """Pre-pack the adjacency for the fused scorer (batch modes only).
 
         Streaming ``adapt`` passes ``plan=None`` — the packing-free flat
@@ -208,14 +211,19 @@ class StrategyBase:
         repack per superstep would cost more than it saves. The batch
         drivers (``converge``/``adapt_rounds``) run many iterations over a
         fixed graph, so one pack amortises across all of them. The plan's
-        kind is kept in ``last_plan`` so the session can report it.
+        kind is kept in ``last_plan`` so the session can report it. The
+        pack runs inside a ``plan.build`` span (kind, shape and the tile
+        bytes uploaded as attributes).
         """
         if backend != "pallas":
             self.last_plan = None
             return None
         from repro.kernels.migration_kernels import build_plan, describe_plan
-        plan = build_plan(graph)
-        self.last_plan = describe_plan(plan)
+        with ctx.tracer.span("plan.build") as sp:
+            plan = build_plan(graph, tracer=ctx.tracer)
+            self.last_plan = describe_plan(plan)
+            sp.set(**self.last_plan, tile_bytes=0 if plan.blocks is None
+                   else plan.blocks.nbytes)
         return plan
 
     def init(self, graph: Graph, k: int) -> jax.Array:
@@ -334,7 +342,7 @@ class OnlineFennel(StrategyBase):
         labels, stats = place_delta(
             delta, ctx.node_mask, ctx.assignment, ctx.occupancy,
             ctx.capacity, ctx.rng, k=ctx.k, passes=passes)
-        ctx.placed = int(stats.placed)
+        ctx.placed = ctx.tracer.host_read(int, stats.placed, "place")
         return labels
 
 
@@ -385,7 +393,8 @@ class XdgpAdaptive(OnlineFennel):
             graph, state, s=ctx.s, patience=ctx.patience,
             max_iters=ctx.max_iters, tie_break=ctx.tie_break,
             rel_tol=ctx.rel_tol, record_history=ctx.record_history,
-            backend=backend, plan=self._plan(graph, backend))
+            backend=backend, plan=self._plan(graph, ctx, backend),
+            tracer=ctx.tracer)
 
     def adapt_rounds(self, graph: Graph, state: PartitionState, iters: int,
                      ctx: StrategyContext) -> Tuple[PartitionState, History]:
@@ -393,7 +402,9 @@ class XdgpAdaptive(OnlineFennel):
         return adapt_rounds(graph, state, iters, s=ctx.s,
                             tie_break=ctx.tie_break,
                             record_history=ctx.record_history,
-                            backend=backend, plan=self._plan(graph, backend))
+                            backend=backend,
+                            plan=self._plan(graph, ctx, backend),
+                            tracer=ctx.tracer)
 
 
 @register_strategy("spinner", "lpa")
@@ -417,7 +428,7 @@ class Spinner(StrategyBase):
         self._adapt_cache: Dict[Tuple[float, float, int, str], Callable] = {}
 
     def _step_fn(self, graph: Graph, ctx: StrategyContext, backend: str):
-        plan = self._plan(graph, backend)
+        plan = self._plan(graph, ctx, backend)
         return lambda st: spinner_step(st, graph, plan,
                                        balance_weight=self.balance_weight,
                                        s=ctx.s, backend=backend)
@@ -441,14 +452,15 @@ class Spinner(StrategyBase):
             graph, state, patience=ctx.patience, max_iters=ctx.max_iters,
             tie_break=ctx.tie_break, rel_tol=ctx.rel_tol,
             record_history=ctx.record_history,
-            step_fn=self._step_fn(graph, ctx, backend))
+            step_fn=self._step_fn(graph, ctx, backend), tracer=ctx.tracer)
 
     def adapt_rounds(self, graph: Graph, state: PartitionState, iters: int,
                      ctx: StrategyContext) -> Tuple[PartitionState, History]:
         backend = resolve_backend(ctx.backend)
         return adapt_rounds(graph, state, iters,
                             record_history=ctx.record_history,
-                            step_fn=self._step_fn(graph, ctx, backend))
+                            step_fn=self._step_fn(graph, ctx, backend),
+                            tracer=ctx.tracer)
 
 
 @register_strategy("sdp")
@@ -469,7 +481,7 @@ class Sdp(OnlineFennel):
         self._adapt_cache: Dict[Tuple[float, int, str], Callable] = {}
 
     def _step_fn(self, graph: Graph, ctx: StrategyContext, backend: str):
-        plan = self._plan(graph, backend)
+        plan = self._plan(graph, ctx, backend)
         return lambda st: sdp_refine_step(st, graph, plan, s=ctx.s,
                                           backend=backend)
 
@@ -492,14 +504,15 @@ class Sdp(OnlineFennel):
             graph, state, patience=ctx.patience, max_iters=ctx.max_iters,
             tie_break=ctx.tie_break, rel_tol=ctx.rel_tol,
             record_history=ctx.record_history,
-            step_fn=self._step_fn(graph, ctx, backend))
+            step_fn=self._step_fn(graph, ctx, backend), tracer=ctx.tracer)
 
     def adapt_rounds(self, graph: Graph, state: PartitionState, iters: int,
                      ctx: StrategyContext) -> Tuple[PartitionState, History]:
         backend = resolve_backend(ctx.backend)
         return adapt_rounds(graph, state, iters,
                             record_history=ctx.record_history,
-                            step_fn=self._step_fn(graph, ctx, backend))
+                            step_fn=self._step_fn(graph, ctx, backend),
+                            tracer=ctx.tracer)
 
 
 @register_strategy("restream", "lemerrer")

@@ -309,7 +309,8 @@ class DynamicGraphSystem:
             k=p.k, s=p.s, adapt_iters=p.adapt_iters, tie_break=p.tie_break,
             placement_passes=p.placement_passes, patience=p.patience,
             max_iters=p.max_iters, rel_tol=p.rel_tol,
-            backend=self.config.compute.backend, **runtime)
+            backend=self.config.compute.backend, tracer=self.tracer,
+            **runtime)
 
     def _place(self, delta: GraphDelta, before: Graph, after: Graph,
                ) -> Tuple[jax.Array, int]:
@@ -323,7 +324,8 @@ class DynamicGraphSystem:
         if ctx.placed is not None:
             placed = ctx.placed
         else:
-            placed = int(jnp.sum(~before.node_mask & after.node_mask))
+            placed = self.tracer.host_read(
+                int, jnp.sum(~before.node_mask & after.node_mask), "place")
         return labels, placed
 
     # -- one superstep ------------------------------------------------------
@@ -335,6 +337,7 @@ class DynamicGraphSystem:
             now = int(ev[:, 0].max()) if ev.size else self._now
         t_start = time.perf_counter()
         tr = self.tracer
+        read = tr.host_read
         sp_step = tr.span("superstep", superstep=self._superstep + 1)
         sp_step.__enter__()
 
@@ -384,13 +387,14 @@ class DynamicGraphSystem:
         # dedupe mode models the live edge set exactly, which makes e_cap
         # exhaustion detectable: apply_delta drops additions silently once
         # free slots run out, and the mirror would drift forever after
-        if cfg.stream.dedupe and \
-                self.ingestor.live_edge_count != int(self.tracker.edges):
-            raise RuntimeError(
-                f"edge capacity exhausted at superstep {self._superstep}: "
-                f"graph holds {int(self.tracker.edges)} live edges but "
-                f"{self.ingestor.live_edge_count} were released "
-                f"(e_cap={after.e_cap}); increase e_cap or lower a_cap")
+        if cfg.stream.dedupe:
+            live_edges = read(int, self.tracker.edges, "step")
+            if self.ingestor.live_edge_count != live_edges:
+                raise RuntimeError(
+                    f"edge capacity exhausted at superstep {self._superstep}: "
+                    f"graph holds {live_edges} live edges but "
+                    f"{self.ingestor.live_edge_count} were released "
+                    f"(e_cap={after.e_cap}); increase e_cap or lower a_cap")
 
         # 5. COMPUTE: one BSP superstep of the vertex program on the adapted
         # graph; its message traffic under the current assignment is the
@@ -406,7 +410,8 @@ class DynamicGraphSystem:
                 self.program_state.block_until_ready()
                 compute_seconds = time.perf_counter() - t_c
                 lb, rb = self._msg_volume(after, state.assignment)
-                local_bytes, remote_bytes = int(lb), int(rb)
+                local_bytes = read(int, lb, "step")
+                remote_bytes = read(int, rb, "step")
 
         # 6. DRIFT CHECK: periodic full recompute validates the tracker
         drift = None
@@ -422,10 +427,11 @@ class DynamicGraphSystem:
             adds=istats.adds_out, dels=istats.dels_out,
             backlog_adds=istats.adds_backlog, backlog_dels=istats.dels_backlog,
             invalid_events=istats.invalid, stale_dropped=istats.stale_dropped,
-            new_placed=new_placed, migrations=int(moved),
-            cut_edges=int(self.tracker.cut), live_edges=int(self.tracker.edges),
-            cut_ratio=float(cut_ratio_of(self.tracker)),
-            imbalance=float(imbalance_of(self.tracker)),
+            new_placed=new_placed, migrations=read(int, moved, "step"),
+            cut_edges=read(int, self.tracker.cut, "step"),
+            live_edges=read(int, self.tracker.edges, "step"),
+            cut_ratio=read(float, cut_ratio_of(self.tracker), "step"),
+            imbalance=read(float, imbalance_of(self.tracker), "step"),
             ingest_seconds=t_ingest,
             step_seconds=time.perf_counter() - t_start,
             drift=drift,
@@ -437,9 +443,9 @@ class DynamicGraphSystem:
             collective_bytes=comm["collective_bytes"],
         )
         self.telemetry.append(record)
-        sp_step.set(migrations=int(moved), cut_ratio=record.cut_ratio)
+        sp_step.set(migrations=record.migrations, cut_ratio=record.cut_ratio)
         sp_step.__exit__(None, None, None)
-        tr.counter("migrations", record.migrations)
+        tr.flush_syncs()
         if self.metrics is not None:
             record_superstep(self.metrics, record,
                              backend=self.backend.name)
@@ -481,26 +487,34 @@ class DynamicGraphSystem:
     # -- batch adaptation (the former AdaptivePartitioner drivers) -----------
     def converge(self, *, record_history: bool = True) -> History:
         """Adapt the current graph to quiescence (paper's convergence rule)."""
-        old = self.state.assignment
-        state, hist = self.backend.converge(
-            self.strategy, self.graph, self.state,
-            self._ctx(record_history=record_history))
-        self.tracker, _ = move_update(self.tracker, self.graph, old,
-                                      state.assignment)
-        self.state = state
-        self.backend.pop_superstep_comm()   # batch comm lands in the totals
+        tr = self.tracer
+        with tr.span("converge") as sp:
+            old = self.state.assignment
+            state, hist = self.backend.converge(
+                self.strategy, self.graph, self.state,
+                self._ctx(record_history=record_history))
+            self.tracker, _ = move_update(self.tracker, self.graph, old,
+                                          state.assignment)
+            self.state = state
+            self.backend.pop_superstep_comm()   # batch comm lands in totals
+            if record_history:
+                sp.set(rounds=hist.iterations)
+            tr.flush_syncs()
         return hist
 
     def adapt(self, iters: int, *, record_history: bool = True) -> History:
         """A fixed number of adaptation rounds on the current graph."""
-        old = self.state.assignment
-        state, hist = self.backend.adapt_rounds(
-            self.strategy, self.graph, self.state, iters,
-            self._ctx(record_history=record_history))
-        self.tracker, _ = move_update(self.tracker, self.graph, old,
-                                      state.assignment)
-        self.state = state
-        self.backend.pop_superstep_comm()   # batch comm lands in the totals
+        tr = self.tracer
+        with tr.span("adapt", rounds=iters):
+            old = self.state.assignment
+            state, hist = self.backend.adapt_rounds(
+                self.strategy, self.graph, self.state, iters,
+                self._ctx(record_history=record_history))
+            self.tracker, _ = move_update(self.tracker, self.graph, old,
+                                          state.assignment)
+            self.state = state
+            self.backend.pop_superstep_comm()   # batch comm lands in totals
+            tr.flush_syncs()
         return hist
 
     def inject(self, delta: GraphDelta) -> int:

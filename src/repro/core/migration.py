@@ -178,57 +178,59 @@ def migrate_step(state: PartitionState, graph: Graph, plan=None, *,
     node_mask = graph.node_mask
 
     # ---- 1. COMMIT deferred migrations from t-1 -------------------------
-    has_pending = state.pending >= 0
-    assignment = jnp.where(has_pending, state.pending, state.assignment)
-    committed = jnp.sum(has_pending & node_mask).astype(jnp.int32)
+    with jax.named_scope("commit"):
+        has_pending = state.pending >= 0
+        assignment = jnp.where(has_pending, state.pending, state.assignment)
+        committed = jnp.sum(has_pending & node_mask).astype(jnp.int32)
 
-    rng, tie_key, sub = jax.random.split(state.rng, 3)
-    if backend == "pallas":
-        # ---- 2–4. fused SCORE + DECIDE + DAMP (DESIGN.md §9) ------------
-        from repro.kernels.migration_kernels import score_select
-        n_cap = graph.n_cap
-        if tie_break == "random":
-            noise = jax.random.uniform(tie_key, (n_cap, k))
+    with jax.named_scope("score"):
+        rng, tie_key, sub = jax.random.split(state.rng, 3)
+        if backend == "pallas":
+            # ---- 2–4. fused SCORE + DECIDE + DAMP (DESIGN.md §9) --------
+            from repro.kernels.migration_kernels import score_select
+            n_cap = graph.n_cap
+            if tie_break == "random":
+                noise = jax.random.uniform(tie_key, (n_cap, k))
+            else:
+                noise = jnp.zeros((n_cap, k), jnp.float32)
+            gate = jax.random.bernoulli(sub, p=s, shape=(n_cap,))
+            _, target, willing, _ = score_select(
+                graph, plan, assignment, node_mask, noise, gate, k,
+                tie_break=tie_break, executor=executor)
+            n_willing = jnp.sum(willing).astype(jnp.int32)
+            rank_fn = partial(_rank_within_group_fast, num_groups=k * k)
+        elif backend == "ref":
+            # ---- 2. SCORE ---------------------------------------------------
+            counts = neighbour_partition_counts(graph, assignment, k,
+                                                chunked=use_chunked_counts)
+
+            # ---- 3. DECIDE --------------------------------------------------
+            target = greedy_targets(counts, assignment, node_mask, rng=tie_key,
+                                    tie_break=tie_break)
+            wants_move = (target != assignment) & node_mask
+
+            # ---- 4. DAMP (Bernoulli(s), paper §3.4) -------------------------
+            gate = jax.random.bernoulli(sub, p=s, shape=wants_move.shape)
+            willing = wants_move & gate
+            n_willing = jnp.sum(willing).astype(jnp.int32)
+            rank_fn = partial(_rank_within_group, num_groups=k * k)
         else:
-            noise = jnp.zeros((n_cap, k), jnp.float32)
-        gate = jax.random.bernoulli(sub, p=s, shape=(n_cap,))
-        _, target, willing, _ = score_select(
-            graph, plan, assignment, node_mask, noise, gate, k,
-            tie_break=tie_break, executor=executor)
-        n_willing = jnp.sum(willing).astype(jnp.int32)
-        rank_fn = partial(_rank_within_group_fast, num_groups=k * k)
-    elif backend == "ref":
-        # ---- 2. SCORE ---------------------------------------------------
-        counts = neighbour_partition_counts(graph, assignment, k,
-                                            chunked=use_chunked_counts)
+            raise ValueError(
+                f"unknown backend {backend!r}; valid: ref, pallas")
 
-        # ---- 3. DECIDE --------------------------------------------------
-        target = greedy_targets(counts, assignment, node_mask, rng=tie_key,
-                                tie_break=tie_break)
-        wants_move = (target != assignment) & node_mask
-
-        # ---- 4. DAMP (Bernoulli(s), paper §3.4) --------------------------
-        gate = jax.random.bernoulli(sub, p=s, shape=wants_move.shape)
-        willing = wants_move & gate
-        n_willing = jnp.sum(willing).astype(jnp.int32)
-        rank_fn = partial(_rank_within_group, num_groups=k * k)
-    else:
-        raise ValueError(f"unknown backend {backend!r}; valid: ref, pallas")
-
-    # ---- 5. QUOTA (paper §3.3) -------------------------------------------
-    occ = occupancy(
-        PartitionState(assignment, state.pending, state.capacity, rng,
-                       state.iteration, state.last_moves), node_mask)
-    free = jnp.maximum(state.capacity - occ, 0)                    # C^j_free(t)
-    quota = free // jnp.maximum(k - 1, 1)                          # Q^{i,j}, same for all i
-    src_part = jnp.clip(assignment, 0, k - 1)
-    group = src_part * k + jnp.clip(target, 0, k - 1)              # (i, j) pair id
-    rank = rank_fn(group, willing)
-    admitted = willing & (rank < quota[jnp.clip(target, 0, k - 1)])
-    n_admitted = jnp.sum(admitted).astype(jnp.int32)
-
-    # ---- 6. DEFER ---------------------------------------------------------
-    pending = jnp.where(admitted, target, jnp.int32(-1))
+    # ---- 5. QUOTA (paper §3.3) + 6. DEFER ---------------------------------
+    with jax.named_scope("quota"):
+        occ = occupancy(
+            PartitionState(assignment, state.pending, state.capacity, rng,
+                           state.iteration, state.last_moves), node_mask)
+        free = jnp.maximum(state.capacity - occ, 0)            # C^j_free(t)
+        quota = free // jnp.maximum(k - 1, 1)      # Q^{i,j}, same for all i
+        src_part = jnp.clip(assignment, 0, k - 1)
+        group = src_part * k + jnp.clip(target, 0, k - 1)      # (i, j) pair id
+        rank = rank_fn(group, willing)
+        admitted = willing & (rank < quota[jnp.clip(target, 0, k - 1)])
+        n_admitted = jnp.sum(admitted).astype(jnp.int32)
+        pending = jnp.where(admitted, target, jnp.int32(-1))
 
     new_state = PartitionState(
         assignment=assignment,

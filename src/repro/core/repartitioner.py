@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import dataclasses
 import warnings
-from typing import Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +25,7 @@ import numpy as np
 from repro.graph.structure import Graph, cut_ratio
 from repro.core.partition_state import PartitionState, make_state, imbalance
 from repro.core.migration import migrate_step, flush_pending
+from repro.obs.trace import NULL_TRACER
 
 
 @dataclasses.dataclass
@@ -69,7 +70,7 @@ def run_to_convergence(graph: Graph, state: PartitionState, *, s: float = 0.5,
                        chunked_counts: bool = False,
                        record_history: bool = True,
                        backend: str = "ref", plan=None,
-                       step_fn=None,
+                       step_fn=None, tracer: Any = NULL_TRACER,
                        ) -> Tuple[PartitionState, History]:
     """Iterate until converged.
 
@@ -85,6 +86,10 @@ def run_to_convergence(graph: Graph, state: PartitionState, *, s: float = 0.5,
     overrides the whole iteration — ``state -> (state, MigrationStats)`` —
     which is how the sharded execution backend reuses this control flow
     (same stopping rule, same history) over the cluster engine.
+
+    Each iteration's reads of its results back to the host (three, five
+    with ``record_history``) run inside an ``adapt.history`` span of
+    ``tracer`` and are counted by it (site ``history``).
     """
     if step_fn is None:
         step_fn = lambda st: migrate_step(st, graph, plan, s=s,
@@ -94,16 +99,19 @@ def run_to_convergence(graph: Graph, state: PartitionState, *, s: float = 0.5,
     quiet = 0
     best_cut = float("inf")
     stale = 0
+    read = tracer.host_read
     for _ in range(max_iters):
         state, stats = step_fn(state)
-        moved = int(stats.committed)
-        pending = int(stats.admitted)
-        cut = float(cut_ratio(graph, state.assignment))
-        if record_history:
-            hist.cut_ratio.append(cut)
-            hist.migrations.append(moved)
-            hist.willing.append(int(stats.willing))
-            hist.imbalance.append(float(imbalance(state, graph.node_mask)))
+        with tracer.span("adapt.history"):
+            moved = read(int, stats.committed, "history")
+            pending = read(int, stats.admitted, "history")
+            cut = read(float, cut_ratio(graph, state.assignment), "history")
+            if record_history:
+                hist.cut_ratio.append(cut)
+                hist.migrations.append(moved)
+                hist.willing.append(read(int, stats.willing, "history"))
+                hist.imbalance.append(read(
+                    float, imbalance(state, graph.node_mask), "history"))
         quiet = quiet + 1 if (moved == 0 and pending == 0) else 0
         if cut < best_cut * (1.0 - rel_tol):
             best_cut = cut
@@ -123,26 +131,33 @@ def adapt_rounds(graph: Graph, state: PartitionState, iters: int, *,
                  chunked_counts: bool = False,
                  record_history: bool = True,
                  backend: str = "ref", plan=None,
-                 step_fn=None,
+                 step_fn=None, tracer: Any = NULL_TRACER,
                  ) -> Tuple[PartitionState, History]:
     """Run a fixed number of adaptation iterations (continuous mode).
 
     Pending moves stay deferred at return (paper §4.2) — the next call's
     first iteration commits them, exactly like the interleaved stream mode.
     ``step_fn`` overrides the iteration like in ``run_to_convergence``.
+    With ``record_history`` each iteration reads four results back to the
+    host, inside an ``adapt.history`` span of ``tracer`` and counted by it
+    (site ``history``); without it the loop reads nothing.
     """
     if step_fn is None:
         step_fn = lambda st: migrate_step(st, graph, plan, s=s,
                                           use_chunked_counts=chunked_counts,
                                           tie_break=tie_break, backend=backend)
     hist = History.empty()
+    read = tracer.host_read
     for _ in range(iters):
         state, stats = step_fn(state)
         if record_history:
-            hist.cut_ratio.append(float(cut_ratio(graph, state.assignment)))
-            hist.migrations.append(int(stats.committed))
-            hist.willing.append(int(stats.willing))
-            hist.imbalance.append(float(imbalance(state, graph.node_mask)))
+            with tracer.span("adapt.history"):
+                hist.cut_ratio.append(read(
+                    float, cut_ratio(graph, state.assignment), "history"))
+                hist.migrations.append(read(int, stats.committed, "history"))
+                hist.willing.append(read(int, stats.willing, "history"))
+                hist.imbalance.append(read(
+                    float, imbalance(state, graph.node_mask), "history"))
     return state, hist
 
 

@@ -38,7 +38,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional, Tuple
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +50,7 @@ from repro import compat
 from repro.graph.structure import Graph
 from repro.kernels import ref
 from repro.kernels.bsr_spmm import max_tiles_per_row
+from repro.obs.trace import NULL_TRACER
 from repro.scale.chunked_bsr import MemoryBudgetError, graph_to_bsr_chunked
 
 
@@ -118,7 +119,8 @@ def describe_plan(plan: MigrationPlan) -> dict:
 
 
 def build_plan(graph: Graph, *, executor: Optional[str] = None,
-               blk: int = 64, ell_max_overhead: float = 4.0) -> MigrationPlan:
+               blk: int = 64, ell_max_overhead: float = 4.0,
+               tracer: Any = NULL_TRACER) -> MigrationPlan:
     """Pack ``graph`` for the fused scorer (host-side numpy).
 
     ``executor`` (default: :func:`repro.compat.pallas_executor`) picks the
@@ -127,22 +129,26 @@ def build_plan(graph: Graph, *, executor: Optional[str] = None,
     plan (still fused) when packing would not pay: a BSR pack larger than
     :func:`plan_memory_budget` is refused before it is allocated, and
     degree skew that would pad ELL beyond
-    ``ell_max_overhead``× the edge count is not packed.
+    ``ell_max_overhead``× the edge count is not packed. Every read of a
+    device array back to the host is counted by ``tracer`` (site ``plan``).
     """
     executor = compat.pallas_executor() if executor is None else executor
+    read = tracer.host_read
     if executor in ("native", "interpret"):
         try:
             bsr = graph_to_bsr_chunked(graph, blk=blk,
-                                       memory_budget=plan_memory_budget())
+                                       memory_budget=plan_memory_budget(),
+                                       tracer=tracer)
         except MemoryBudgetError:
             return FLAT_PLAN                  # the tiles would not fit
         return MigrationPlan(
             kind="bsr", blocks=bsr.blocks, block_cols=bsr.block_cols,
             row_ptr=bsr.row_ptr,
-            max_per_row=max_tiles_per_row(np.asarray(bsr.row_ptr)))
-    em = np.asarray(graph.edge_mask)
-    s = np.asarray(graph.src)[em].astype(np.int64)
-    d = np.asarray(graph.dst)[em].astype(np.int64)
+            max_per_row=max_tiles_per_row(read(np.asarray, bsr.row_ptr,
+                                               "plan")))
+    em = read(np.asarray, graph.edge_mask, "plan")
+    s = read(np.asarray, graph.src, "plan")[em].astype(np.int64)
+    d = read(np.asarray, graph.dst, "plan")[em].astype(np.int64)
     src2 = np.concatenate([s, d])
     dst2 = np.concatenate([d, s])
     n_cap = graph.n_cap
@@ -308,6 +314,7 @@ def pallas_score_select(blocks: jax.Array, block_cols: jax.Array,
             jax.ShapeDtypeStruct((n_blocks, 1, blk), jnp.float32),
         ],
         interpret=interpret,
+        name="pallas_score_select",
     )(row_ptr, block_cols, blocks, lab_b, lab_b, mask_b, noise_b, gate_b)
     n_pad = n_blocks * blk
     return (counts.transpose(0, 2, 1).reshape(n_pad, k), target.reshape(n_pad),

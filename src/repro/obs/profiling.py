@@ -1,25 +1,19 @@
-"""Kernel profiling hooks + roofline cost estimates for migration plans
-(DESIGN.md §11).
+"""Roofline cost estimates for migration plans and host-memory probes
+(DESIGN.md §11, §14).
 
-Two complementary views of the fused migration kernels
-(``repro.kernels.migration_kernels``):
-
-* ``kernel_profile(logdir)`` — optional ``jax.profiler`` capture around a
-  region (XPlane/TensorBoard format; on TPU this is the real per-kernel
-  timeline).  Profiling is strictly opt-in and failure-tolerant: hosts
-  without a working profiler get a disabled no-op capture, never a crash
-  on the hot path.
-* ``plan_cost(plan, graph, k)`` — an analytic FLOP/byte bill of one fused
-  score/select pass over a ``MigrationPlan``, per packing kind, with the
-  same peak numbers ``benchmarks/roofline.py`` uses (imported from here so
-  the constants have one home).  Comparing a measured ``kernel/score``
-  span against ``t_bound`` says how far the kernel sits from the roofline.
+``plan_cost(plan, graph, k)`` is an analytic FLOP/byte bill of one fused
+score/select pass over a ``MigrationPlan`` (``repro.kernels.
+migration_kernels``), per packing kind, with the same peak numbers
+``benchmarks/roofline.py`` uses (imported from here so the constants have
+one home).  Comparing a measured ``kernel/score`` span against ``t_bound``
+says how far the kernel sits from the roofline.  A device timeline of the
+kernels comes from any ``jax.profiler`` capture, where the session's spans
+appear too (``repro.obs.trace``).
 """
 from __future__ import annotations
 
-import contextlib
 import sys
-from typing import Any, Dict, Iterator, Optional
+from typing import Any, Dict, Optional
 
 import numpy as np
 
@@ -28,37 +22,6 @@ import numpy as np
 PEAK_FLOPS = 197e12           # bf16 FLOP/s per chip
 HBM_BW = 819e9                # HBM bytes/s per chip
 ICI_BW = 50e9                 # bytes/s per ICI link (conservative)
-
-
-@contextlib.contextmanager
-def kernel_profile(logdir: Optional[str],
-                   enabled: bool = True) -> Iterator[Dict[str, Any]]:
-    """Optional ``jax.profiler`` capture around a region.
-
-    Yields a status dict: ``{"enabled": bool, "logdir": ..., "error": ...}``.
-    Disabled (``logdir=None`` / ``enabled=False``) or failing captures are
-    no-ops — profiling must never take down the run it observes.
-    """
-    status: Dict[str, Any] = {"enabled": False, "logdir": logdir,
-                              "error": None}
-    if not enabled or logdir is None:
-        yield status
-        return
-    try:
-        import jax
-        jax.profiler.start_trace(logdir)
-        status["enabled"] = True
-    except Exception as e:                           # pragma: no cover
-        status["error"] = repr(e)
-        yield status
-        return
-    try:
-        yield status
-    finally:
-        try:
-            jax.profiler.stop_trace()
-        except Exception as e:                       # pragma: no cover
-            status["error"] = repr(e)
 
 
 def _proc_status_kb(field: str) -> Optional[int]:

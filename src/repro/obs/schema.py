@@ -30,6 +30,10 @@ def _num(d: Dict[str, Any], key: str, line: int) -> None:
              f"line {line}: {key!r} must be a number, got {d.get(key)!r}")
 
 
+def _index(v: Any) -> bool:
+    return isinstance(v, int) and not isinstance(v, bool) and v >= 0
+
+
 # ---------------------------------------------------------------------------
 # trace JSONL
 # ---------------------------------------------------------------------------
@@ -46,6 +50,13 @@ def validate_trace_line(obj: Dict[str, Any], line: int = 0) -> None:
         _require(obj["dur_us"] >= 0, f"line {line}: negative dur_us")
         _num(obj, "depth", line)
         _require(obj["depth"] >= 0, f"line {line}: negative depth")
+        if "id" in obj:
+            _require(_index(obj["id"]), f"line {line}: 'id' must be a "
+                     f"non-negative integer, got {obj['id']!r}")
+        if "parent" in obj:
+            _require(obj["parent"] is None or _index(obj["parent"]),
+                     f"line {line}: 'parent' must be null or a span id, "
+                     f"got {obj['parent']!r}")
         if "attrs" in obj:
             _require(isinstance(obj["attrs"], dict),
                      f"line {line}: attrs must be an object")

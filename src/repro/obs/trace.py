@@ -17,9 +17,23 @@ numbers honest:
   allocation and no fencing unless ``SystemConfig.telemetry.trace`` turned
   tracing on (the overhead budget is §11's <3%).
 
-Spans nest: depth is tracked per tracer, and the Chrome export relies on
-timestamp containment (Perfetto renders same-track ``X`` events as a flame
-graph).  Two exports share one in-memory event list:
+Spans nest: every span event carries its ``id``, its ``parent`` (the span
+open around it, None at the root) and its ``depth``, so a reader gets self
+time as duration minus children; the Chrome export relies on timestamp
+containment (Perfetto renders same-track ``X`` events as a flame graph).
+While a span is open it also holds a ``jax.profiler.TraceAnnotation``
+named ``xdgp/<span>``, so any ``jax.profiler`` capture shows the program's
+phases on the capture's own clock, nested as they ran, beside the device's
+ops.
+
+Two kinds of counter come from inside the program: ``host_syncs`` — the
+device→host reads made through ``host_read`` since the last
+``flush_syncs`` (one sample per superstep, ``adapt()`` or ``converge()``,
+the count per call site in its attrs) — and ``compile_s`` — each backend
+compile or persistent-cache load JAX reports while the tracer lives
+(``stage`` in its attrs).
+
+Two exports share one in-memory event list:
 
 * ``write_jsonl(path)``  — one JSON object per line; first line is a
   ``meta`` header (schema version, clock, run manifest).  This is the file
@@ -33,9 +47,12 @@ from __future__ import annotations
 import json
 import os
 import time
-from typing import Any, Dict, List, Optional
+import weakref
+from typing import Any, Callable, Dict, List, Optional, Tuple, TypeVar
 
 import jax
+
+T = TypeVar("T")
 
 TRACE_SCHEMA_VERSION = 1
 
@@ -83,6 +100,13 @@ class NullTracer:
     def add_span(self, name: str, duration_s: float, **attrs: Any) -> None:
         pass
 
+    def host_read(self, conv: Callable[[Any], T], value: Any,
+                  site: str) -> T:
+        return conv(value)
+
+    def flush_syncs(self) -> None:
+        pass
+
     def __repr__(self) -> str:
         return "<NullTracer (tracing disabled)>"
 
@@ -93,13 +117,17 @@ NULL_TRACER = NullTracer()
 class Span:
     """One live span: created by ``Tracer.span``, used as a context manager."""
 
-    __slots__ = ("_tracer", "name", "attrs", "_t0")
+    __slots__ = ("_tracer", "name", "attrs", "_t0", "_id", "_parent",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self._tracer = tracer
         self.name = name
         self.attrs = attrs
         self._t0 = 0
+        self._id = 0
+        self._parent: Optional[int] = None
+        self._annotation: Any = None
 
     def set(self, **attrs: Any) -> None:
         """Attach attributes to the span (recorded at exit)."""
@@ -113,15 +141,20 @@ class Span:
 
     def __enter__(self) -> "Span":
         tr = self._tracer
-        tr._depth += 1
+        self._id, self._parent = tr._new_span_id()
+        tr._open.append(self._id)
+        self._annotation = jax.profiler.TraceAnnotation("xdgp/" + self.name)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
         t1 = time.perf_counter_ns()
+        self._annotation.__exit__(*exc)
         tr = self._tracer
-        tr._depth -= 1
-        tr._emit(self.name, self._t0, t1 - self._t0, tr._depth, self.attrs)
+        tr._open.pop()
+        tr._emit(self.name, self._t0, t1 - self._t0, self.attrs, self._id,
+                 self._parent)
         return False
 
 
@@ -132,9 +165,12 @@ class Tracer:
 
     def __init__(self, *, meta: Optional[Dict[str, Any]] = None):
         self._origin = time.perf_counter_ns()
-        self._depth = 0
+        self._open: List[int] = []      # ids of the open spans, innermost last
+        self._next_id = 0
+        self.syncs: Dict[str, int] = {}  # reads since flush_syncs, by site
         self.meta: Dict[str, Any] = dict(meta or {})
         self.events: List[Dict[str, Any]] = []
+        _watch_compiles(self)
 
     # -- recording ----------------------------------------------------------
     def span(self, name: str, **attrs: Any) -> Span:
@@ -146,13 +182,21 @@ class Tracer:
         for a in arrays:
             jax.block_until_ready(a)
 
-    def _emit(self, name: str, t0_ns: int, dur_ns: int, depth: int,
-              attrs: Dict[str, Any]) -> None:
+    def _new_span_id(self) -> Tuple[int, Optional[int]]:
+        """A fresh span id and the id of the span open around it (None at
+        the root)."""
+        sid = self._next_id
+        self._next_id += 1
+        return sid, (self._open[-1] if self._open else None)
+
+    def _emit(self, name: str, t0_ns: int, dur_ns: int,
+              attrs: Dict[str, Any], sid: int, parent: Optional[int]) -> None:
         ev: Dict[str, Any] = {
             "type": "span", "name": name,
             "ts_us": (t0_ns - self._origin) / 1000.0,
             "dur_us": dur_ns / 1000.0,
-            "depth": depth,
+            "depth": len(self._open),
+            "id": sid, "parent": parent,
         }
         if attrs:
             ev["attrs"] = attrs
@@ -160,10 +204,25 @@ class Tracer:
 
     def add_span(self, name: str, duration_s: float, **attrs: Any) -> None:
         """Record a synthetic span ending *now* with a known duration —
-        how probe-measured phases (comm decomposition) enter the trace."""
+        how probe-measured phases (comm decomposition) enter the trace.
+        It has no profiler annotation: it did not run as one interval."""
         t1 = time.perf_counter_ns()
         dur_ns = int(duration_s * 1e9)
-        self._emit(name, t1 - dur_ns, dur_ns, self._depth, attrs)
+        self._emit(name, t1 - dur_ns, dur_ns, attrs, *self._new_span_id())
+
+    def host_read(self, conv: Callable[[Any], T], value: Any,
+                  site: str) -> T:
+        """``conv(value)`` — a blocking device→host read such as
+        ``float(x)`` or ``np.asarray(x)`` — counted under ``site``."""
+        self.syncs[site] = self.syncs.get(site, 0) + 1
+        return conv(value)
+
+    def flush_syncs(self) -> None:
+        """Record the ``host_syncs`` counter — the reads since the last
+        flush, with the count of each site as an attribute — and restart
+        the count."""
+        self.counter("host_syncs", sum(self.syncs.values()), **self.syncs)
+        self.syncs = {}
 
     def counter(self, name: str, value: float, **attrs: Any) -> None:
         """Record a counter sample (renders as a counter track in Perfetto)."""
@@ -234,3 +293,33 @@ class Tracer:
 
     def __repr__(self) -> str:
         return f"<Tracer events={len(self.events)}>"
+
+
+# -- compile time from inside the program -----------------------------------
+# JAX reports each backend compile (a persistent-cache load included) and
+# each cache load through ``jax.monitoring``; one listener, registered with
+# the first Tracer, forwards them to every live Tracer as ``compile_s``.
+COMPILE_STAGES = {
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval",
+}
+_COMPILE_WATCHERS: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
+_compile_listener_registered = False
+
+
+def _on_compile_event(event: str, duration_s: float, **_: Any) -> None:
+    if not _COMPILE_WATCHERS:
+        return
+    stage = COMPILE_STAGES.get(event)
+    if stage is not None:
+        for tracer in list(_COMPILE_WATCHERS):
+            tracer.counter("compile_s", duration_s, stage=stage)
+
+
+def _watch_compiles(tracer: Tracer) -> None:
+    global _compile_listener_registered
+    if not _compile_listener_registered:
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_event)
+        _compile_listener_registered = True
+    _COMPILE_WATCHERS.add(tracer)

@@ -32,13 +32,15 @@ may allocate for the packed blocks + key set; exceeding it raises
 """
 from __future__ import annotations
 
-from typing import Iterator, Optional, Tuple
+import dataclasses
+from typing import Any, Iterator, Optional, Tuple
 
 import numpy as np
 import jax.numpy as jnp
 
 from repro.graph.bsr import BSRMatrix, check_int32_index
 from repro.graph.structure import Graph
+from repro.obs.trace import NULL_TRACER
 
 
 class MemoryBudgetError(MemoryError):
@@ -75,12 +77,18 @@ def graph_to_bsr_chunked(graph: Graph, blk: int = 128,
                          normalize: Optional[str] = None,
                          nnzb_cap: Optional[int] = None, dtype=np.float32,
                          chunk_edges: int = 1 << 20,
-                         memory_budget: Optional[int] = None) -> BSRMatrix:
+                         memory_budget: Optional[int] = None,
+                         tracer: Any = NULL_TRACER) -> BSRMatrix:
     """Two-pass chunked equivalent of ``graph_to_bsr`` — same signature
     plus the chunk size and an optional byte budget for the packed output.
+    The graph's edge arrays are read back to the host once each, counted
+    by ``tracer`` (site ``plan``); every pass then reads the host copies.
     """
     if normalize not in (None, "sym", "row"):
         raise ValueError(normalize)
+    graph = dataclasses.replace(graph, **{
+        f: tracer.host_read(np.asarray, getattr(graph, f), "plan")
+        for f in ("edge_mask", "src", "dst")})
     n_cap = graph.n_cap
     n_pad = -(-n_cap // blk) * blk
     n_blocks = n_pad // blk

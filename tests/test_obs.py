@@ -29,8 +29,8 @@ from repro.api.config import GraphSection, TelemetrySection
 from repro.api.telemetry import SuperstepRecord
 from repro.graph import generators
 from repro.obs import (MetricsRegistry, NULL_TRACER, Tracer, config_hash,
-                       kernel_profile, plan_cost, record_cluster,
-                       record_superstep, run_manifest)
+                       plan_cost, record_cluster, record_superstep,
+                       run_manifest)
 from repro.obs.report import main as report_main
 from repro.obs.schema import (SchemaError, validate_metrics_file,
                               validate_trace_file, validate_trace_line)
@@ -143,6 +143,38 @@ def test_tracer_spans_nest_and_export(tmp_path):
     totals = tr.phase_totals()
     assert totals["superstep"]["count"] == 1
     assert totals["comm/halo_exchange"]["total_s"] == pytest.approx(0.002)
+
+
+def test_span_events_name_their_parent():
+    tr = Tracer()
+    with tr.span("adapt"):
+        with tr.span("plan.build"):
+            pass
+        with tr.span("adapt.history"):
+            tr.add_span("probe", 0.001)
+    by = {e["name"]: e for e in tr.events}
+    assert by["adapt"]["parent"] is None and by["adapt"]["depth"] == 0
+    assert by["plan.build"]["parent"] == by["adapt"]["id"]
+    assert by["adapt.history"]["parent"] == by["adapt"]["id"]
+    assert by["probe"]["parent"] == by["adapt.history"]["id"]
+    assert by["probe"]["depth"] == 2
+    assert len({e["id"] for e in tr.events}) == 4
+    for i, ev in enumerate(tr.events):
+        validate_trace_line(ev, line=i)
+    # self time: the parent's duration less its children's
+    kids = sum(e["dur_us"] for e in tr.events
+               if e["parent"] == by["adapt"]["id"])
+    assert by["adapt"]["dur_us"] - kids >= 0
+
+
+@pytest.mark.parametrize("bad", [{"id": -1}, {"id": "a"}, {"parent": -2},
+                                 {"parent": True}])
+def test_trace_schema_rejects_bad_span_ids(bad):
+    line = {"type": "span", "name": "x", "ts_us": 0, "dur_us": 1,
+            "depth": 0, "id": 0, "parent": None}
+    validate_trace_line(line)
+    with pytest.raises(SchemaError):
+        validate_trace_line(dict(line, **bad))
 
 
 def test_trace_schema_rejects_bad_lines(tmp_path):
@@ -269,21 +301,12 @@ def test_plan_cost_all_kinds():
     assert c["kind"] == "flat" and c["live_edges2"] == c["edges2"]
 
 
-def test_kernel_profile_disabled_is_noop():
-    with kernel_profile(None) as status:
-        pass
-    assert status["enabled"] is False and status["error"] is None
-    with kernel_profile("/tmp/x", enabled=False) as status:
-        pass
-    assert status["enabled"] is False
-
-
 # ---------------------------------------------------------------------------
 # Traced sessions: local smoke, disabled null path, overhead budget
 # ---------------------------------------------------------------------------
 
 LOCAL_PHASES = {"superstep", "ingest", "place", "migrate",
-                "kernel/score_select", "commit"}
+                "adapt", "commit"}
 SHARDED_PHASES = {"superstep", "ingest", "place", "migrate", "commit",
                   "cluster/bucket", "cluster/recompile", "cluster/dispatch",
                   "cluster/host_sync", "cluster/flush", "obs/comm_probe",
@@ -316,6 +339,129 @@ def test_disabled_session_is_null_path():
     assert NULL_TRACER.span("x") is _NULL_SPAN
     assert NULL_TRACER.span("y", a=1) is _NULL_SPAN
     _NULL_SPAN.fence(jnp.ones(2))                 # no-op, takes anything
+
+
+def test_null_tracer_reads_exactly_and_records_nothing():
+    # the null path of the new hooks: the shared span, the bare conversion
+    x = jnp.arange(4, dtype=jnp.int32).sum()
+    for conv in (int, float):
+        got = NULL_TRACER.host_read(conv, x, "history")
+        assert type(got) is conv and got == conv(x)
+    arr = NULL_TRACER.host_read(np.asarray, jnp.ones(3), "plan")
+    assert isinstance(arr, np.ndarray)
+    NULL_TRACER.flush_syncs()
+    with NULL_TRACER.span("adapt.history") as sp:
+        assert sp is _NULL_SPAN
+    assert NULL_TRACER.events == ()
+    assert not hasattr(NULL_TRACER, "syncs")
+
+
+def _batch_session(trace: bool, backend: str = "pallas"):
+    from repro.api.config import ComputeSection
+    cfg = SystemConfig(partition=PartitionSection(strategy="xdgp", k=4),
+                       compute=ComputeSection(backend=backend),
+                       telemetry=TelemetrySection(trace=trace))
+    return DynamicGraphSystem(generators.fem_grid2d(8), cfg)
+
+
+@pytest.mark.parametrize("record_history", [True, False])
+def test_host_syncs_count_history_and_pack_reads(record_history):
+    """One ``host_syncs`` sample per adapt(): four reads a round with the
+    history on, plus the pack's reads (edge_mask, src, dst for the CPU's
+    ELL plan)."""
+    rounds = 3
+    system = _batch_session(trace=True)
+    system.adapt(rounds, record_history=record_history)
+    syncs = [e for e in system.tracer.events
+             if e["type"] == "counter" and e["name"] == "host_syncs"]
+    assert len(syncs) == 1
+    want = {"plan": 3}
+    if record_history:
+        want["history"] = 4 * rounds
+    assert syncs[0]["attrs"] == want
+    assert syncs[0]["value"] == sum(want.values())
+    assert system.tracer.syncs == {}              # flushed
+    spans = [e["name"] for e in system.tracer.events if e["type"] == "span"]
+    assert spans.count("adapt.history") == (rounds if record_history else 0)
+    assert spans.count("plan.build") == 1 and spans[-1] == "adapt"
+
+
+@pytest.mark.parametrize("executor,reads", [("jax", 3), ("interpret", 4)])
+def test_pack_reads_are_counted(executor, reads):
+    """The BSR pack reads edge_mask, src and dst once each, and row_ptr
+    back after its upload; the ELL pack the first three."""
+    from repro.kernels.migration_kernels import build_plan
+    tr = Tracer()
+    plan = build_plan(generators.fem_grid2d(8), executor=executor, blk=8,
+                      tracer=tr)
+    assert plan.kind in ("ell", "bsr")
+    assert tr.syncs == {"plan": reads}
+
+
+def test_converge_and_steps_flush_host_syncs():
+    system = _batch_session(trace=True)
+    hist = system.converge()
+    by = {e["name"]: e for e in system.tracer.events}
+    assert by["converge"]["attrs"]["rounds"] == hist.iterations
+    # convergence reads three results a round, five with the history
+    assert by["host_syncs"]["attrs"] == {"plan": 3,
+                                         "history": 5 * hist.iterations}
+    stream = _session(trace=True)
+    for i in range(2):
+        stream.step(_events(200, 200, seed=i))
+    syncs = [e for e in stream.tracer.events if e["name"] == "host_syncs"]
+    assert len(syncs) == 2 and all(s["attrs"]["step"] >= 5 for s in syncs)
+    assert not [e for e in stream.tracer.events
+                if e["name"] == "migrations"]
+
+
+def test_compile_time_reaches_traced_sessions_only(monkeypatch):
+    import weakref
+    from repro.obs import trace as trace_mod
+    plain = _batch_session(trace=False)
+    traced = _batch_session(trace=True)
+    fresh = jax.jit(lambda x: jnp.cumsum(x * 3 + 1))
+    fresh(jnp.ones((7, 13, 3))).block_until_ready()
+    compiles = [e for e in traced.tracer.events
+                if e["type"] == "counter" and e["name"] == "compile_s"]
+    assert compiles and sum(e["value"] for e in compiles) > 0
+    assert {e["attrs"]["stage"] for e in compiles} <= set(
+        trace_mod.COMPILE_STAGES.values())
+    assert plain.tracer.events == ()
+    # with no tracer alive the listener returns before looking at anything
+    monkeypatch.setattr(trace_mod, "_COMPILE_WATCHERS", weakref.WeakSet())
+    trace_mod._on_compile_event(
+        "/jax/core/compile/backend_compile_duration", 1.0)
+    assert plain.tracer.events == ()
+
+
+def test_profiler_capture_nests_program_spans(tmp_path):
+    """A jax.profiler capture of a traced adapt(3) holds the program's
+    spans, on the capture's clock and nested as they ran."""
+    from benchmarks.chip import spans as chip_spans
+    system = _batch_session(trace=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        system.adapt(3)
+        jax.block_until_ready(system.labels)
+    finally:
+        jax.profiler.stop_trace()
+    got = chip_spans.load(str(tmp_path))
+    names = [s["name"] for s in got]
+    assert names.count("xdgp/adapt") == 1
+    assert names.count("xdgp/plan.build") == 1
+    assert names.count("xdgp/adapt.history") == 3
+    top = next(s for s in got if s["name"] == "xdgp/adapt")
+    assert top["depth"] == 0
+    for s in got:
+        if s["name"] in ("xdgp/plan.build", "xdgp/adapt.history"):
+            assert s["depth"] == 1 and s["track"] == top["track"]
+            assert top["start_ns"] <= s["start_ns"]
+            assert (s["start_ns"] + s["dur_ns"]
+                    <= top["start_ns"] + top["dur_ns"])
+    # the program's own record of the same spans is unchanged beside it
+    assert [e["name"] for e in system.tracer.events
+            if e["type"] == "span"].count("adapt.history") == 3
 
 
 def test_tracing_overhead_under_3pct():
