@@ -373,9 +373,10 @@ class ShardedBackend:
         The migrator handles the slot↔block permutation on device, so one
         iteration is one jit dispatch — no host round-trips.
 
-        ``unshard_each`` places every returned state back on the default
-        device: the batch drivers interleave the step with single-device
-        jits (cut history, flush) that must not see this mesh's sharding.
+        ``unshard_each`` places every returned state and its stats back on
+        the default device: the batch drivers interleave the step with
+        single-device jits (round quality, history, flush) that must not
+        see this mesh's sharding.
         The streaming ``adapt`` loop keeps the state mesh-resident instead
         and unshards once at the end."""
         mig = self._migrator(ctx, state)
@@ -390,20 +391,21 @@ class ShardedBackend:
             new_state = PartitionState(
                 assignment=a, pending=p, capacity=state.capacity, rng=rng,
                 iteration=state.iteration + 1, last_moves=committed)
+            stats = MigrationStats(committed=committed, willing=willing,
+                                   admitted=admitted)
             if unshard_each:
-                new_state = self._unshard(new_state)
-            return new_state, MigrationStats(committed=committed,
-                                             willing=willing,
-                                             admitted=admitted)
+                new_state, stats = self._unshard((new_state, stats))
+            return new_state, stats
 
         return step
 
     @staticmethod
-    def _unshard(state: PartitionState) -> PartitionState:
-        """Place the final state back on the default device: the session's
-        own jits (tracker updates, vertex program) must not inherit this
-        mesh's sharding — it may be gone after a gather()/rescale()."""
-        return jax.device_put(state, jax.devices()[0])
+    def _unshard(tree: Any) -> Any:
+        """Place a state (and its stats) back on the default device: the
+        session's own jits (tracker updates, vertex program) must not
+        inherit this mesh's sharding — it may be gone after a
+        gather()/rescale()."""
+        return jax.device_put(tree, jax.devices()[0])
 
     # -- comm probe (DESIGN.md §11) ----------------------------------------
     def _probe_comm(self, state, ctx) -> None:

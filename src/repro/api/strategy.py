@@ -64,13 +64,14 @@ from repro.compat import resolve_backend
 from repro.core.initial import (block_partition, deterministic_greedy,
                                 hash_partition, min_neighbours,
                                 modulo_partition, random_partition)
-from repro.core.partition_state import PartitionState, imbalance
+from repro.core.partition_state import PartitionState
 from repro.core.repartitioner import (History, adapt_jit, adapt_rounds,
+                                      read_rounds, round_row,
                                       run_to_convergence)
 from repro.core.restream import restream_state
 from repro.core.sdp import sdp_adapt_jit, sdp_refine_step
 from repro.core.spinner import spinner_adapt_jit, spinner_step
-from repro.graph.structure import Graph, GraphDelta, cut_ratio
+from repro.graph.structure import Graph, GraphDelta
 from repro.obs.trace import NULL_TRACER
 from repro.stream.placement import place_delta
 
@@ -547,30 +548,22 @@ class Restream(OnlineFennel):
         state, _ = restream_state(state, graph)
         return state
 
-    def _record(self, hist: History, graph: Graph, state: PartitionState,
-                moved: int, record: bool) -> None:
-        if record:
-            hist.cut_ratio.append(float(cut_ratio(graph, state.assignment)))
-            hist.migrations.append(moved)
-            hist.willing.append(moved)
-            hist.imbalance.append(float(imbalance(state, graph.node_mask)))
-
     def converge(self, graph: Graph, state: PartitionState,
                  ctx: StrategyContext) -> Tuple[PartitionState, History]:
         hist = History.empty()
         for _ in range(ctx.max_iters):
             state, stats = restream_state(state, graph)
-            moved = int(stats.committed)
-            self._record(hist, graph, state, moved, ctx.record_history)
-            if moved == 0:
+            if ctx.record_history:
+                hist.add_rounds(*read_rounds(
+                    graph, [round_row(graph, state, stats)], ctx.tracer),
+                    state.k)
+            if int(stats.committed) == 0:
                 break
         return state, hist
 
     def adapt_rounds(self, graph: Graph, state: PartitionState, iters: int,
                      ctx: StrategyContext) -> Tuple[PartitionState, History]:
-        hist = History.empty()
-        for _ in range(iters):
-            state, stats = restream_state(state, graph)
-            self._record(hist, graph, state, int(stats.committed),
-                         ctx.record_history)
-        return state, hist
+        return adapt_rounds(graph, state, iters,
+                            record_history=ctx.record_history,
+                            step_fn=lambda st: restream_state(st, graph),
+                            tracer=ctx.tracer)

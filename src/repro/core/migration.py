@@ -14,6 +14,9 @@ Fully vectorised SPMD formulation of the paper's per-vertex loop:
                 is a deterministic within-group prefix count (order-free).
   6. DEFER    — admitted moves are written to ``pending``; they commit at the
                 start of the next iteration (step 1).
+  7. QUALITY  — the committed assignment's cut edges, E − ½·Σ_v
+                counts[v, label(v)], and its occupancy max and sum, from
+                what steps 2 and 5 already hold (``MigrationStats``).
 
 Steps 2–4 have two implementations behind ``migrate_step``'s static
 ``backend`` switch (DESIGN.md §9): ``"ref"`` is the unfused op-by-op
@@ -33,12 +36,19 @@ import jax.numpy as jnp
 
 from repro.graph.structure import Graph
 from repro.core.partition_state import PartitionState, occupancy
+from repro.kernels.ref import same_label_pairs
 
 
 class MigrationStats(NamedTuple):
+    """One iteration's counts. The quality fields describe the committed
+    assignment the iteration scored; a step that leaves them ``None`` has
+    its quality computed by the batch drivers instead."""
     committed: jax.Array     # () int32 — migrations committed this iteration
     willing: jax.Array       # () int32 — vertices that wanted to move (post-damping)
     admitted: jax.Array      # () int32 — moves admitted by quotas (== next commit)
+    cut_edges: Optional[jax.Array] = None      # () int32 — live edges cut
+    occupancy_max: Optional[jax.Array] = None  # () int32 — max |P^i| (live)
+    occupancy_sum: Optional[jax.Array] = None  # () int32 — sum |P^i| (live)
 
 
 def neighbour_partition_counts(graph: Graph, assignment: jax.Array, k: int,
@@ -194,7 +204,7 @@ def migrate_step(state: PartitionState, graph: Graph, plan=None, *,
             else:
                 noise = jnp.zeros((n_cap, k), jnp.float32)
             gate = jax.random.bernoulli(sub, p=s, shape=(n_cap,))
-            _, target, willing, _ = score_select(
+            counts, target, willing, _ = score_select(
                 graph, plan, assignment, node_mask, noise, gate, k,
                 tie_break=tie_break, executor=executor)
             n_willing = jnp.sum(willing).astype(jnp.int32)
@@ -232,6 +242,19 @@ def migrate_step(state: PartitionState, graph: Graph, plan=None, *,
         n_admitted = jnp.sum(admitted).astype(jnp.int32)
         pending = jnp.where(admitted, target, jnp.int32(-1))
 
+    # ---- 7. QUALITY of the committed assignment, from the round's own
+    # counts and occupancy: no gather over the edges ------------------------
+    with jax.named_scope("quality"):
+        # The barrier keeps XLA from fusing the reduction into the fused
+        # scorer's output. Fused, XLA moved the kernel's label operands
+        # out of VMEM: on a v5e at FEM-64 the kernel ran 2.7% slower than
+        # with no reduction, and through the barrier 3.5% faster.
+        same = same_label_pairs(*jax.lax.optimization_barrier(
+            (counts, assignment)))
+        cut = (graph.num_edges - same // 2).astype(jnp.int32)
+        occ_max = jnp.max(occ).astype(jnp.int32)
+        occ_sum = jnp.sum(occ).astype(jnp.int32)
+
     new_state = PartitionState(
         assignment=assignment,
         pending=pending,
@@ -240,8 +263,9 @@ def migrate_step(state: PartitionState, graph: Graph, plan=None, *,
         iteration=state.iteration + 1,
         last_moves=committed,
     )
-    return new_state, MigrationStats(committed=committed, willing=n_willing,
-                                     admitted=n_admitted)
+    return new_state, MigrationStats(
+        committed=committed, willing=n_willing, admitted=n_admitted,
+        cut_edges=cut, occupancy_max=occ_max, occupancy_sum=occ_sum)
 
 
 @jax.jit

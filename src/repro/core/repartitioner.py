@@ -4,7 +4,8 @@ The paper's convergence criterion: zero migrations for 30 consecutive
 iterations. ``run_to_convergence`` is a host loop around the jit'd
 ``migrate_step`` so we can record per-iteration history (cut ratio,
 migrations) exactly like the paper's figures; ``adapt_rounds`` runs a fixed
-number of iterations (continuous mode); ``converge_jit`` is a pure
+number of iterations (continuous mode) and reads its history back once, at
+the end; ``converge_jit`` is a pure
 ``lax.while_loop`` variant for embedding the adaptation inside larger jit
 programs (the distributed engine uses it).
 
@@ -22,9 +23,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.graph.structure import Graph, cut_ratio
-from repro.core.partition_state import PartitionState, make_state, imbalance
-from repro.core.migration import migrate_step, flush_pending
+from repro.graph.structure import Graph, cut_edges
+from repro.core.partition_state import PartitionState, make_state, occupancy
+from repro.core.migration import MigrationStats, migrate_step, flush_pending
 from repro.obs.trace import NULL_TRACER
 
 
@@ -63,6 +64,53 @@ class History:
     def empty() -> "History":
         return History([], [], [], [])
 
+    def add_rounds(self, rows: np.ndarray, edges: int, k: int) -> None:
+        """Append rounds read by ``read_rounds``. The ratios are formed here
+        alone, from the integers, in float32 as ``cut_ratio`` and
+        ``imbalance`` form them on the device."""
+        f32 = np.float32
+        cut = rows[:, _CUT].astype(f32) / f32(max(edges, 1))
+        mean = np.maximum(rows[:, _OCC_SUM].astype(f32) / f32(k), f32(1))
+        self.cut_ratio.extend(cut.tolist())
+        self.migrations.extend(rows[:, _COMMITTED].tolist())
+        self.willing.extend(rows[:, _WILLING].tolist())
+        self.imbalance.extend(
+            (rows[:, _OCC_MAX].astype(f32) / mean).tolist())
+
+
+# the columns of a ``round_row``, in ``MigrationStats``'s field order
+_COMMITTED, _WILLING, _ADMITTED, _CUT, _OCC_MAX, _OCC_SUM = range(6)
+
+# rounds ``adapt_rounds`` dispatches ahead of the device: enough to keep it
+# fed, few enough that the queued rounds' states stay a bounded set
+_AHEAD = 8
+
+
+@jax.jit
+def round_row(graph: Graph, state: PartitionState,
+              stats: MigrationStats) -> jax.Array:
+    """One round's integers as a (6,) int32 row, on the device and without
+    a read: committed, willing, admitted, cut edges, occupancy max and sum.
+    A step that does not report its quality (its fields are None) gets it
+    from ``state`` here."""
+    if stats.cut_edges is None:
+        occ = occupancy(state, graph.node_mask)
+        stats = stats._replace(cut_edges=cut_edges(graph, state.assignment),
+                               occupancy_max=jnp.max(occ),
+                               occupancy_sum=jnp.sum(occ))
+    return jnp.stack([jnp.asarray(v, jnp.int32) for v in stats])
+
+
+def read_rounds(graph: Graph, rows: List[jax.Array],
+                tracer: Any = NULL_TRACER) -> Tuple[np.ndarray, int]:
+    """The rounds' rows (``round_row``) and the graph's live edges in one
+    blocking read, inside an ``adapt.history`` span of ``tracer`` and
+    counted by it (site ``history``): an (R, 6) int array, and E."""
+    with tracer.span("adapt.history"):
+        rows, edges = tracer.host_read(
+            jax.device_get, (rows, graph.num_edges), "history")
+    return np.stack(rows), int(edges)
+
 
 def run_to_convergence(graph: Graph, state: PartitionState, *, s: float = 0.5,
                        patience: int = 30, max_iters: int = 500,
@@ -87,9 +135,8 @@ def run_to_convergence(graph: Graph, state: PartitionState, *, s: float = 0.5,
     which is how the sharded execution backend reuses this control flow
     (same stopping rule, same history) over the cluster engine.
 
-    Each iteration's reads of its results back to the host (three, five
-    with ``record_history``) run inside an ``adapt.history`` span of
-    ``tracer`` and are counted by it (site ``history``).
+    Each iteration reads its round back to the host once
+    (``read_rounds``): the stopping rule needs its numbers.
     """
     if step_fn is None:
         step_fn = lambda st: migrate_step(st, graph, plan, s=s,
@@ -99,19 +146,13 @@ def run_to_convergence(graph: Graph, state: PartitionState, *, s: float = 0.5,
     quiet = 0
     best_cut = float("inf")
     stale = 0
-    read = tracer.host_read
     for _ in range(max_iters):
         state, stats = step_fn(state)
-        with tracer.span("adapt.history"):
-            moved = read(int, stats.committed, "history")
-            pending = read(int, stats.admitted, "history")
-            cut = read(float, cut_ratio(graph, state.assignment), "history")
-            if record_history:
-                hist.cut_ratio.append(cut)
-                hist.migrations.append(moved)
-                hist.willing.append(read(int, stats.willing, "history"))
-                hist.imbalance.append(read(
-                    float, imbalance(state, graph.node_mask), "history"))
+        rows, edges = read_rounds(graph, [round_row(graph, state, stats)],
+                                  tracer)
+        hist.add_rounds(rows, edges, state.k)
+        moved, pending = hist.migrations[-1], int(rows[0, _ADMITTED])
+        cut = hist.cut_ratio[-1]
         quiet = quiet + 1 if (moved == 0 and pending == 0) else 0
         if cut < best_cut * (1.0 - rel_tol):
             best_cut = cut
@@ -123,7 +164,7 @@ def run_to_convergence(graph: Graph, state: PartitionState, *, s: float = 0.5,
         if tie_break == "random" and stale >= patience:
             break
     state = flush_pending(state, graph)
-    return state, hist
+    return state, (hist if record_history else History.empty())
 
 
 def adapt_rounds(graph: Graph, state: PartitionState, iters: int, *,
@@ -138,26 +179,28 @@ def adapt_rounds(graph: Graph, state: PartitionState, iters: int, *,
     Pending moves stay deferred at return (paper §4.2) — the next call's
     first iteration commits them, exactly like the interleaved stream mode.
     ``step_fn`` overrides the iteration like in ``run_to_convergence``.
-    With ``record_history`` each iteration reads four results back to the
-    host, inside an ``adapt.history`` span of ``tracer`` and counted by it
-    (site ``history``); without it the loop reads nothing.
+    No round reads: with ``record_history`` each keeps its ``round_row``
+    on the device and the loop reads them all back once at the end
+    (``read_rounds``); without it the loop reads nothing. Once it has
+    dispatched round t the host waits for round t − ``_AHEAD`` to finish,
+    so the rounds queued on the device, and the states they hold, stay a
+    bounded set whatever ``iters`` is.
     """
     if step_fn is None:
         step_fn = lambda st: migrate_step(st, graph, plan, s=s,
                                           use_chunked_counts=chunked_counts,
                                           tie_break=tie_break, backend=backend)
     hist = History.empty()
-    read = tracer.host_read
+    rows, queued = [], []
     for _ in range(iters):
         state, stats = step_fn(state)
+        queued.append(stats.committed)
+        if len(queued) > _AHEAD:
+            jax.block_until_ready(queued.pop(0))
         if record_history:
-            with tracer.span("adapt.history"):
-                hist.cut_ratio.append(read(
-                    float, cut_ratio(graph, state.assignment), "history"))
-                hist.migrations.append(read(int, stats.committed, "history"))
-                hist.willing.append(read(int, stats.willing, "history"))
-                hist.imbalance.append(read(
-                    float, imbalance(state, graph.node_mask), "history"))
+            rows.append(round_row(graph, state, stats))
+    if rows:
+        hist.add_rounds(*read_rounds(graph, rows, tracer), state.k)
     return state, hist
 
 
@@ -184,7 +227,8 @@ class AdaptivePartitioner:
         state, stats = migrate_step(state, graph, s=self.config.s,
                                     use_chunked_counts=self.config.chunked_counts,
                                     tie_break=self.config.tie_break)
-        return state, {k: int(v) for k, v in stats._asdict().items()}
+        return state, {k: int(v) for k, v in stats._asdict().items()
+                       if v is not None}
 
     def run_to_convergence(self, graph: Graph, state: PartitionState,
                            record_history: bool = True,

@@ -121,6 +121,16 @@ def ref_score_select(counts: jax.Array, assignment: jax.Array,
     return target, willing, gain
 
 
+def same_label_pairs(counts: jax.Array, labels: jax.Array) -> jax.Array:
+    """Σ_v counts[v, labels[v]] over (n, k) ``counts``; labels outside
+    ``[0, k)`` count nothing. On the scorer's counts this is twice the
+    live edges inside a partition, so cut = E − same/2 without a gather
+    over the edges. Summed as int32, exact at any size."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, counts.shape, 1)
+    hit = iota == labels[:, None]
+    return jnp.sum(jnp.where(hit, counts.astype(jnp.int32), 0))
+
+
 def ref_embedding_bag(table: jax.Array, indices: jax.Array,
                       combine: str = "sum") -> jax.Array:
     """(V,D) table, (B,n_hot) indices (−1 pad) → (B,D)."""

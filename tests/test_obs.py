@@ -366,9 +366,9 @@ def _batch_session(trace: bool, backend: str = "pallas"):
 
 @pytest.mark.parametrize("record_history", [True, False])
 def test_host_syncs_count_history_and_pack_reads(record_history):
-    """One ``host_syncs`` sample per adapt(): four reads a round with the
-    history on, plus the pack's reads (edge_mask, src, dst for the CPU's
-    ELL plan)."""
+    """One ``host_syncs`` sample per adapt(): one read of every round's
+    history, in one ``adapt.history`` span, with the history on, plus the
+    pack's reads (edge_mask, src, dst for the CPU's ELL plan)."""
     rounds = 3
     system = _batch_session(trace=True)
     system.adapt(rounds, record_history=record_history)
@@ -377,12 +377,12 @@ def test_host_syncs_count_history_and_pack_reads(record_history):
     assert len(syncs) == 1
     want = {"plan": 3}
     if record_history:
-        want["history"] = 4 * rounds
+        want["history"] = 1
     assert syncs[0]["attrs"] == want
     assert syncs[0]["value"] == sum(want.values())
     assert system.tracer.syncs == {}              # flushed
     spans = [e["name"] for e in system.tracer.events if e["type"] == "span"]
-    assert spans.count("adapt.history") == (rounds if record_history else 0)
+    assert spans.count("adapt.history") == (1 if record_history else 0)
     assert spans.count("plan.build") == 1 and spans[-1] == "adapt"
 
 
@@ -403,9 +403,9 @@ def test_converge_and_steps_flush_host_syncs():
     hist = system.converge()
     by = {e["name"]: e for e in system.tracer.events}
     assert by["converge"]["attrs"]["rounds"] == hist.iterations
-    # convergence reads three results a round, five with the history
+    # convergence reads each round back once, for its stopping rule
     assert by["host_syncs"]["attrs"] == {"plan": 3,
-                                         "history": 5 * hist.iterations}
+                                         "history": hist.iterations}
     stream = _session(trace=True)
     for i in range(2):
         stream.step(_events(200, 200, seed=i))
@@ -450,7 +450,7 @@ def test_profiler_capture_nests_program_spans(tmp_path):
     names = [s["name"] for s in got]
     assert names.count("xdgp/adapt") == 1
     assert names.count("xdgp/plan.build") == 1
-    assert names.count("xdgp/adapt.history") == 3
+    assert names.count("xdgp/adapt.history") == 1
     top = next(s for s in got if s["name"] == "xdgp/adapt")
     assert top["depth"] == 0
     for s in got:
@@ -461,7 +461,7 @@ def test_profiler_capture_nests_program_spans(tmp_path):
                     <= top["start_ns"] + top["dur_ns"])
     # the program's own record of the same spans is unchanged beside it
     assert [e["name"] for e in system.tracer.events
-            if e["type"] == "span"].count("adapt.history") == 3
+            if e["type"] == "span"].count("adapt.history") == 1
 
 
 def test_tracing_overhead_under_3pct():
