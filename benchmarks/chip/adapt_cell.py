@@ -1,7 +1,8 @@
 """Runner of the ``adapt`` mode: batch adaptation from a hash start, again
 and again for the whole window.
 
-Set-up (timed as ``setup_s``): the graph is built on the host and one
+Set-up (timed as ``setup_s``): the configuration's graph is built (the FEM
+lattice on the host, a Kronecker graph on the device from the seed) and one
 ``adapt(rounds)`` runs on a fresh session (it compiles, or loads from the
 persistent cache, every program an adaptation runs).
 
@@ -27,34 +28,21 @@ from typing import Dict, List
 import jax
 import numpy as np
 
-from . import cost, gen, xplane
+from . import cost, deployment, xplane
 from .harness import Cell, CompileCounter, Outcome, memory_peak
 from .reference import partition
-
-
-def _session(cell: Cell, graph, seed: int):
-    from repro.api import DynamicGraphSystem, SystemConfig
-    from repro.api.config import ComputeSection, PartitionSection
-    s = cell.config["session"]
-    cfg = SystemConfig(
-        partition=PartitionSection(strategy="xdgp", k=s["k"], s=s["s"],
-                                   slack=s["slack"]),
-        compute=ComputeSection(backend=s["compute_backend"]), seed=seed)
-    return DynamicGraphSystem(graph, cfg)
-
-
-def _graph(cell: Cell):
-    side = cell.config["graph"]["side"]
-    src, dst = gen.fem_cube_edges(side)
-    from repro.graph.structure import from_edges
-    return from_edges(src, dst, side ** 3)
 
 
 def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
         started: float, devices) -> Outcome:
     rounds = cell.traffic["rounds"]
-    graph = _graph(cell)
-    system = _session(cell, graph, seed)
+    graph = deployment.graph(cell, seed)
+
+    def new_session(session_seed: int):
+        return deployment.session(cell, graph, session_seed, trace=False,
+                                  devices=devices, stream=False)
+
+    system = new_session(seed)
     system.adapt(rounds)
     jax.block_until_ready(system.labels)
     plan = system.scoring_plan
@@ -71,7 +59,7 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
     def repetition() -> float:
         r = len(results)
         t = time.perf_counter()
-        system = _session(cell, graph, seed + r)
+        system = new_session(seed + r)
         jax.block_until_ready((system.labels, system.tracker.cut))
         resets.append(time.perf_counter() - t)
         t = time.perf_counter()
@@ -97,12 +85,10 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
                 repetition()
             jax.profiler.stop_trace()
             events = xplane.load(logdir, trace_lines)
-            marks = [e for e in events if e["kind"] == "host"]
-            if marks:
-                lo = min(e["start_ns"] for e in marks)
-                hi = max(e["start_ns"] + e["dur_ns"] for e in marks)
-                profile = xplane.reduce(events, (lo, hi),
-                                        kernel=cell.config["kernel_op"])
+            window_ns = xplane.annotated(events)
+            if window_ns:
+                profile = xplane.reduce(events, window_ns,
+                                        kernel=cell.config.get("kernel_op"))
         finally:
             shutil.rmtree(logdir, ignore_errors=True)
 
@@ -175,12 +161,12 @@ def _reference(cell: Cell, graph, seed: int, low: bool):
             "pending": np.asarray(pending), "cut": int(cut)}
 
 
-def control(cell: Cell, replay: Dict):
+def control(cell: Cell, seed: int, replay: Dict):
     """The comparison with the reference in bfloat16 put in the session's
-    place, for the repetitions' seeds of a run."""
-    graph = _graph(cell)
-    finals = [_reference(cell, graph, seed, low=True)
-              for seed in replay["seeds"]]
+    place, for the repetitions' seeds of a run of ``seed``."""
+    graph = deployment.graph(cell, seed)
+    finals = [_reference(cell, graph, rep_seed, low=True)
+              for rep_seed in replay["seeds"]]
     return compare(cell, graph, finals)[0]
 
 

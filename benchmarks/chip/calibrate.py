@@ -31,7 +31,7 @@ def main() -> int:
     os.environ["JAX_COMPILATION_CACHE_DIR"] = os.path.join(_ROOT,
                                                            ".jax_cache")
     import jax
-    from chip import adapt_cell, harness, stream_cell
+    from chip import harness
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     cell = harness.load_cell(args.workload)
     devices = harness.chips(cell.chips)[:cell.chips]
@@ -41,10 +41,8 @@ def main() -> int:
                                trace=False, started=started,
                                devices=devices)
         t = time.perf_counter()
-        if cell.traffic["mode"] == "adapt":
-            control = adapt_cell.control(cell, out.run["replay"])
-        else:
-            control = stream_cell.control(cell, seed, out.run["replay"])
+        control = harness.runner(cell.traffic["mode"]).control(
+            cell, seed, out.run["replay"])
         print(json.dumps({
             "seed": seed, "correct": out.correct,
             "program": {k: c["value"] for k, c in out.checks.items()},

@@ -156,19 +156,26 @@ def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
 
 
+def runner(mode: str):
+    """The module that runs a traffic ``mode``: its ``run`` drives a cell
+    once, its ``control`` puts the reference in bfloat16 in the session's
+    place on a run's own inputs."""
+    if mode == "stream":
+        from . import stream_cell
+        return stream_cell
+    if mode == "adapt":
+        from . import adapt_cell
+        return adapt_cell
+    raise BenchError(f"unknown traffic mode {mode!r}: stream or adapt")
+
+
 def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
              started: float, devices) -> Outcome:
     """Drive ``cell`` once; ``started`` is the process's start on the
     ``time.perf_counter`` clock (set-up is timed from there)."""
-    mode = cell.traffic["mode"]
-    if mode == "stream":
-        from .stream_cell import run
-    elif mode == "adapt":
-        from .adapt_cell import run
-    else:
-        raise BenchError(f"unknown traffic mode {mode!r}: stream or adapt")
-    return run(cell, seed=seed, seconds=seconds, trace=trace,
-               started=started, devices=devices)
+    return runner(cell.traffic["mode"]).run(
+        cell, seed=seed, seconds=seconds, trace=trace, started=started,
+        devices=devices)
 
 
 def result_line(cell: Cell, outcome: Outcome, *, trace: bool, devices

@@ -33,15 +33,15 @@ def main() -> int:
                                                            ".jax_cache")
     import jax
     import numpy as np
-    from chip import gen, harness, stream_cell
-    from repro.graph.structure import Graph
+    from chip import deployment, gen, harness
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     cell = harness.load_cell(args.workload)
-    harness.chips(cell.chips)
-    g, a_cap = cell.config["graph"], cell.config["session"]["a_cap"]
-    src, dst, em, nm, _ = stream_cell.base_graph(cell, args.seed)
-    system = stream_cell._session(cell, Graph(src, dst, nm, em), args.seed,
-                                  False)
+    devices = harness.chips(cell.chips)[:cell.chips]
+    sess = cell.config["session"]
+    g, a_cap = deployment.graph_spec(cell, "kronecker"), sess["a_cap"]
+    graph = deployment.graph(cell, args.seed, sess["stream_edge_slots"])
+    system = deployment.session(cell, graph, args.seed, trace=False,
+                                devices=devices, stream=True)
     total = 2 * a_cap + int(sum(args.rates) * args.seconds) + a_cap
     events = gen.stream_events(args.seed, total, scale=g["scale"], a=g["A"],
                                b=g["B"], c=g["C"])

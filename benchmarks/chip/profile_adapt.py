@@ -43,20 +43,6 @@ PROGRAM_METRICS = ("plan_build_ms.adapt", "history_idle_ms.adapt",
                    "host_syncs.adapt", "compile_s.adapt")
 
 
-def _session(cell, graph, seed: int, trace: bool):
-    # the cell's session (adapt_cell._session) with telemetry.trace set
-    from repro.api import DynamicGraphSystem, SystemConfig
-    from repro.api.config import (ComputeSection, PartitionSection,
-                                  TelemetrySection)
-    s = cell.config["session"]
-    cfg = SystemConfig(
-        partition=PartitionSection(strategy="xdgp", k=s["k"], s=s["s"],
-                                   slack=s["slack"]),
-        compute=ComputeSection(backend=s["compute_backend"]),
-        telemetry=TelemetrySection(trace=trace), seed=seed)
-    return DynamicGraphSystem(graph, cfg)
-
-
 def _compile_s(events: List[Dict]) -> Dict[str, float]:
     out: Dict[str, float] = {}
     for e in events:
@@ -81,7 +67,7 @@ def main() -> int:
     import numpy as np
     from repro.obs.trace import Tracer
     process = Tracer(meta={"label": "process"})     # every compile, set-up on
-    from chip import adapt_cell, cost, harness, spans, xplane
+    from chip import cost, deployment, harness, spans, xplane
 
     over: Dict[str, Any] = {"config": {}, "traffic": {}}
     if args.side:
@@ -90,17 +76,23 @@ def main() -> int:
         over["traffic"]["rounds"] = args.rounds
     cell = harness.load_cell(args.workload, over)
     rounds = cell.traffic["rounds"]
-    device = jax.devices()[0]
+    devices = jax.devices()[:cell.chips]
+    device = devices[0]
+
+    def _session(seed: int, trace: bool):
+        return deployment.session(cell, graph, seed, trace=trace,
+                                  devices=devices, stream=False)
+
     harness.log(f"device: {device.platform} {device.device_kind}; "
                 f"{cell.name}, seed {args.seed}, rounds {rounds}")
 
     phases: Dict[str, float] = {}
     t = time.perf_counter()
-    graph = adapt_cell._graph(cell)
+    graph = deployment.graph(cell, args.seed)
     jax.block_until_ready(graph.edge_mask)
     phases["graph"] = time.perf_counter() - t
     t = time.perf_counter()
-    system = _session(cell, graph, args.seed, trace=True)
+    system = _session(args.seed, trace=True)
     jax.block_until_ready((system.labels, system.tracker.cut))
     phases["session"] = time.perf_counter() - t
     t = time.perf_counter()
@@ -117,7 +109,7 @@ def main() -> int:
     for i in range(args.pairs):
         pair: Dict[str, float] = {}
         for trace in ((False, True) if i % 2 == 0 else (True, False)):
-            s = _session(cell, graph, args.seed + 1 + i, trace)
+            s = _session(args.seed + 1 + i, trace)
             jax.block_until_ready((s.labels, s.tracker.cut))
             t = time.perf_counter()
             s.adapt(rounds)
@@ -134,17 +126,14 @@ def main() -> int:
     try:
         jax.profiler.start_trace(logdir)
         with jax.profiler.TraceAnnotation("bench/adapt"):
-            system = _session(cell, graph, args.seed + 1000, trace=True)
+            system = _session(args.seed + 1000, trace=True)
             jax.block_until_ready((system.labels, system.tracker.cut))
             system.adapt(rounds)
             jax.block_until_ready((system.labels, system.tracker.cut))
         jax.profiler.stop_trace()
         events = xplane.load(logdir, lines)
         program = spans.load(logdir)
-        marks = [e for e in events if e["kind"] == "host"]
-        lo = min(e["start_ns"] for e in marks)
-        hi = max(e["start_ns"] + e["dur_ns"] for e in marks)
-        profile = xplane.reduce(events, (lo, hi),
+        profile = xplane.reduce(events, xplane.annotated(events),
                                 kernel=cell.config["kernel_op"],
                                 spans=program)
         if args.keep_trace:

@@ -9,7 +9,10 @@ are loaded too.
 
 Window: for ``--seconds``, each superstep takes every event due by the time
 it starts (events are due at fixed offsets, whatever the session does) and
-returns once its device work is done. ``events_per_s`` is the events the
+returns once its device work is done. The generator starts
+``lead_seconds`` before the window opens, so a stream offered above the
+session's capacity opens the window with a full queue, as it would have
+in its steady state, and not with the one event due at its first instant. ``events_per_s`` is the events the
 window's supersteps committed over the time until the last of them
 returned; ``commit_p95_ms`` is the 95th percentile, over every event due
 in the window, of due → return of the superstep that committed it. Where
@@ -18,7 +21,9 @@ new events, until every event due in the window is committed.
 
 With ``--trace 1`` the session records its phase spans (they fence each
 phase, so the window's timing is not the untraced one) and, after the
-window, ``profile_supersteps`` more supersteps run under the profiler.
+window, ``profile_supersteps`` more supersteps run under the profiler; the
+capture holds the same spans as ``xdgp/<name>`` on its own clock, and they
+name the device's idle gaps.
 
 Check: the plain reference replays every superstep the session ran, on the
 same generated graph and the same batches, and the run is held to it.
@@ -35,57 +40,9 @@ from typing import Any, Dict, List
 import jax
 import numpy as np
 
-from . import gen, stats, xplane
+from . import deployment, gen, spans, stats, xplane
 from .harness import BenchError, Cell, CompileCounter, Outcome, memory_peak
 from .reference.session import Replay
-
-INFINITE_WINDOW = 1 << 40
-
-
-def _session(cell: Cell, graph, seed: int, trace: bool):
-    from repro.api import DynamicGraphSystem, SystemConfig
-    from repro.api.config import (ComputeSection, PartitionSection,
-                                  StreamSection, TelemetrySection)
-    s = cell.config["session"]
-    cfg = SystemConfig(
-        stream=StreamSection(window=INFINITE_WINDOW, a_cap=s["a_cap"],
-                             d_cap=s["d_cap"]),
-        partition=PartitionSection(strategy="xdgp", k=s["k"], s=s["s"],
-                                   adapt_iters=s["adapt_iters"],
-                                   slack=s["slack"]),
-        compute=ComputeSection(program=s["program"],
-                               backend=s["compute_backend"]),
-        telemetry=TelemetrySection(recompute_every=s["recompute_every"],
-                                   trace=trace),
-        seed=seed)
-    return DynamicGraphSystem(graph, cfg)
-
-
-def base_graph(cell: Cell, seed: int):
-    g = cell.config["graph"]
-    n = 1 << g["scale"]
-    m = g["edgefactor"] * n
-    u, v = gen.kronecker_edges(seed, gen.BASE_EDGES, scale=g["scale"], m=m,
-                               a=g["A"], b=g["B"], c=g["C"])
-    e_cap = m + cell.config["session"]["stream_edge_slots"]
-    return gen.dedupe_graph(u, v, n=n, e_cap=e_cap)
-
-
-def _profile_window(events, annotations, spans):
-    """Reduce the capture: window = first to last annotation; the session's
-    spans are moved onto the trace's clock by the first annotation, which
-    opened on the host clock reading recorded beside it."""
-    marks = sorted((e for e in events if e["kind"] == "host"),
-                   key=lambda e: e["start_ns"])
-    if not marks:
-        return None
-    offset = marks[0]["start_ns"] - annotations[0]
-    lo = marks[0]["start_ns"]
-    hi = marks[-1]["start_ns"] + marks[-1]["dur_ns"]
-    moved = [{"name": s["name"], "depth": s["depth"],
-              "start_ns": s["ts_ns"] + offset, "dur_ns": s["dur_ns"]}
-             for s in spans]
-    return xplane.reduce(events, (lo, hi), spans=moved)
 
 
 def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
@@ -93,24 +50,24 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
     t_setup = {}
     tr = cell.traffic
     sess = cell.config["session"]
-    g = cell.config["graph"]
+    g = deployment.graph_spec(cell, "kronecker")   # the live stream's too
     a_cap = sess["a_cap"]
 
     t = time.perf_counter()
-    src, dst, edge_mask, node_mask, base_edges = base_graph(cell, seed)
-    from repro.graph.structure import Graph
-    graph = Graph(src=src, dst=dst, node_mask=node_mask, edge_mask=edge_mask)
-    base_edges = int(base_edges)
+    graph = deployment.graph(cell, seed, sess["stream_edge_slots"])
+    base_edges = int(graph.edge_mask.sum())
     rate = tr["rate_share_of_knee"] * tr["knee_events_per_s"]
     warm = tr["warmup_supersteps"] * a_cap
-    count = warm + math.ceil(rate * (seconds + tr["tail_seconds"]))
+    lead = tr["lead_seconds"]
+    count = warm + math.ceil(rate * (lead + seconds + tr["tail_seconds"]))
     events = gen.stream_events(seed, count, scale=g["scale"], a=g["A"],
                                b=g["B"], c=g["C"])
-    due = gen.due_offsets(tr["arrivals"], rate, count - warm, seed)
+    due = gen.due_offsets(tr["arrivals"], rate, count - warm, seed) - lead
     t_setup["generate"] = time.perf_counter() - t
 
     t = time.perf_counter()
-    system = _session(cell, graph, seed, trace)
+    system = deployment.session(cell, graph, seed, trace=trace,
+                                devices=devices, stream=True)
     del graph
     t_setup["session"] = time.perf_counter() - t
 
@@ -163,29 +120,18 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
     trace_lines: Dict[str, int] = {}
     if trace:
         logdir = tempfile.mkdtemp(prefix="chip-trace-")
-        marks: List[int] = []
-        prof_spans: List[Dict[str, Any]] = []
         try:
-            n_before = len(tracer.events)
             jax.profiler.start_trace(logdir)
             for _ in range(tr["profile_supersteps"]):
                 with jax.profiler.TraceAnnotation("bench/superstep"):
-                    marks.append(time.perf_counter_ns())
                     now = time.perf_counter() - t0
                     superstep(now, now)
             jax.profiler.stop_trace()
-            # the session's spans carry microseconds since its tracer's
-            # origin; the first superstep span opens right after marks[0]
-            new = tracer.events[n_before:]
-            spans = [e for e in new if e["type"] == "span"]
-            first = min(s["ts_us"] for s in spans if s["name"] == "superstep")
-            for s in spans:
-                prof_spans.append({
-                    "name": s["name"], "depth": s["depth"],
-                    "ts_ns": marks[0] + (s["ts_us"] - first) * 1e3,
-                    "dur_ns": s["dur_us"] * 1e3})
-            profile = _profile_window(xplane.load(logdir, trace_lines), marks,
-                                      prof_spans)
+            captured = xplane.load(logdir, trace_lines)
+            window_ns = xplane.annotated(captured)
+            if window_ns:
+                profile = xplane.reduce(captured, window_ns,
+                                        spans=spans.load(logdir))
         finally:
             shutil.rmtree(logdir, ignore_errors=True)
 
@@ -269,7 +215,8 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
 
 def _replay(cell: Cell, seed: int, low: bool) -> Replay:
     sess = cell.config["session"]
-    src, dst, edge_mask, node_mask, _ = base_graph(cell, seed)
+    src, dst, edge_mask, node_mask, _ = deployment.kronecker_arrays(
+        cell, seed, sess["stream_edge_slots"])
     return Replay(src, dst, edge_mask, node_mask, k=sess["k"], s=sess["s"],
                   slack=sess["slack"], adapt_iters=sess["adapt_iters"],
                   a_cap=sess["a_cap"], seed=seed, low=low)

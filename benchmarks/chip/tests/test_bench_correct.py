@@ -10,27 +10,25 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chip import adapt_cell, stream_cell
-from chip.tests.cpu import run_tiny, tiny_cell
+from chip import harness
+from chip.tests.cpu import cells, run_tiny, tiny_cell
 
-STREAM = ("g500-s18-sat", "g500-s18-paced")
+CELLS = cells()
+MODE = {w: harness.load_cell(w).traffic["mode"] for w in CELLS}
 
 
-@pytest.mark.parametrize("workload", STREAM + ("fem64-adapt",))
+@pytest.mark.parametrize("workload", CELLS)
 def test_cell_is_correct(workload):
     out = run_tiny(workload)
     assert out.correct, out.checks
     assert out.failed == 0 and out.attempted > 0
 
 
-@pytest.mark.parametrize("workload", STREAM + ("fem64-adapt",))
+@pytest.mark.parametrize("workload", CELLS)
 def test_control_is_refused(workload):
     out = run_tiny(workload)
-    runner = adapt_cell if workload == "fem64-adapt" else stream_cell
-    replay = out.run["replay"]
-    checks = (runner.control(tiny_cell(workload), replay)
-              if runner is adapt_cell else
-              runner.control(tiny_cell(workload), 7, replay))
+    checks = harness.runner(MODE[workload]).control(tiny_cell(workload), 7,
+                                                    out.run["replay"])
     assert any(c["value"] > c["limit"] for c in checks.values()), checks
 
 
@@ -84,13 +82,11 @@ def _half_graph(monkeypatch):
     monkeypatch.setattr(rep, "migrate_step", half)
 
 
-FAULTS = [("g500-s18-sat", _state_unchanged), ("g500-s18-sat", _half_batch),
-          ("g500-s18-sat", _answer_altered),
-          ("g500-s18-paced", _state_unchanged),
-          ("g500-s18-paced", _half_batch),
-          ("g500-s18-paced", _answer_altered),
-          ("fem64-adapt", _state_unchanged), ("fem64-adapt", _half_graph),
-          ("fem64-adapt", _answer_altered)]
+# the faults a cell of each mode can have: a stream has batches to halve,
+# a batch adaptation a graph to score only half of
+MODE_FAULTS = {"stream": (_state_unchanged, _half_batch, _answer_altered),
+               "adapt": (_state_unchanged, _half_graph, _answer_altered)}
+FAULTS = [(w, f) for w in CELLS for f in MODE_FAULTS[MODE[w]]]
 
 
 @pytest.mark.parametrize("workload,fault", FAULTS,

@@ -50,3 +50,25 @@ def test_op_names_from_a_chip_trace():
               _op(xplane.op_name(scatter), 12, 20)]
     r = xplane.reduce(events, (0, 20), kernel="pallas_score_select")
     assert r["kernel_events"] == 1 and r["kernel_s"] == pytest.approx(1e-8)
+
+
+def test_idle_gap_inside_a_program_phase_is_named_by_it():
+    """The capture's program spans (``spans.load``: ``xdgp/<name>``, depth
+    from containment) name a gap by the innermost phase running in it."""
+    from chip import spans
+    events = [_op("fusion.1", 0, 10), _op("fusion.2", 30, 60),
+              _op("fusion.3", 70, 100),
+              {"kind": "host", "track": "python", "name": "bench/superstep",
+               "start_ns": 0, "dur_ns": 100}]
+    program = spans.nest([
+        {"track": "python", "name": "xdgp/superstep", "start_ns": 0,
+         "dur_ns": 100},
+        {"track": "python", "name": "xdgp/ingest", "start_ns": 0,
+         "dur_ns": 12},
+        {"track": "python", "name": "xdgp/place", "start_ns": 12,
+         "dur_ns": 50},
+        {"track": "python", "name": "xdgp/migrate", "start_ns": 62,
+         "dur_ns": 38}])
+    r = xplane.reduce(events, xplane.annotated(events), spans=program)
+    assert dict(r["idle_gaps"]) == pytest.approx(
+        {"xdgp/place": 20e-9, "xdgp/migrate": 10e-9})

@@ -63,6 +63,16 @@ def load(logdir: str, lines: Optional[Dict[str, int]] = None
     return events
 
 
+def annotated(events: Sequence[Dict]) -> Optional[Tuple[float, float]]:
+    """(start_ns, end_ns) from the first benchmark annotation's start to the
+    last one's end; None where the capture holds none."""
+    marks = [e for e in events if e["kind"] == "host"]
+    if not marks:
+        return None
+    return (min(e["start_ns"] for e in marks),
+            max(e["start_ns"] + e["dur_ns"] for e in marks))
+
+
 def op_name(hlo: str) -> str:
     """``%fusion.80 = s32[2097153]{0:T(1024)} fusion(...)`` → ``fusion.80
     s32[2097153]``: the op and the shape it makes, not its operands."""
