@@ -29,7 +29,7 @@ import jax
 import numpy as np
 
 from . import cost, deployment, xplane
-from .harness import Cell, CompileCounter, Outcome, memory_peak
+from .harness import Cell, CompileCounter, Outcome, memory_peaks
 from .reference import partition
 
 
@@ -88,11 +88,12 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
             window_ns = xplane.annotated(events)
             if window_ns:
                 profile = xplane.reduce(events, window_ns,
-                                        kernel=cell.config.get("kernel_op"))
+                                        kernel=cell.config.get("kernel_op"),
+                                        chips=len(devices))
         finally:
             shutil.rmtree(logdir, ignore_errors=True)
 
-    peak = memory_peak(devices)
+    peak = max(memory_peaks(devices))
     # the reference takes ~2 s a repetition: hold a sample drawn from the
     # seed to it, the profiled repetition always among them
     take = min(cell.traffic["checked_repetitions"], len(results))

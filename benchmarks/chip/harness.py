@@ -146,10 +146,10 @@ def chips(count: int):
     return devices
 
 
-def memory_peak(devices) -> int:
-    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-             for d in devices]
-    return int(max(peaks)) if peaks else 0
+def memory_peaks(devices) -> List[int]:
+    """Each chip's peak bytes in use so far (0 where it reports none)."""
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in devices]
 
 
 def log(msg: str) -> None:

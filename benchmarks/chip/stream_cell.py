@@ -41,7 +41,8 @@ import jax
 import numpy as np
 
 from . import deployment, gen, spans, stats, xplane
-from .harness import BenchError, Cell, CompileCounter, Outcome, memory_peak
+from .harness import (BenchError, Cell, CompileCounter, Outcome,
+                      memory_peaks)
 from .reference.session import Replay
 
 
@@ -131,7 +132,8 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
             window_ns = xplane.annotated(captured)
             if window_ns:
                 profile = xplane.reduce(captured, window_ns,
-                                        spans=spans.load(logdir))
+                                        spans=spans.load(logdir),
+                                        chips=len(devices))
         finally:
             shutil.rmtree(logdir, ignore_errors=True)
 
@@ -151,7 +153,7 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
     if inserted > sess["stream_edge_slots"]:
         raise BenchError(f"the run inserted {inserted} edges, more than the "
                          f"{sess['stream_edge_slots']} spare edge slots")
-    peak = memory_peak(devices)
+    peaks = memory_peaks(devices)
     final = {
         "labels": np.asarray(system.labels),
         "pending": np.asarray(system.state.pending),
@@ -183,6 +185,9 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
              f"in {span_s:.3f} s, backlog at end {backlog_end}; "
              f"executables built in the window {counter.built}, loaded from "
              f"the persistent cache {counter.cache_hits}; batch plan {plan}",
+             f"superstep times in the window (s): "
+             f"{[round(w['ret'] - w['start'], 4) for w in steps]}",
+             f"memory peak per chip (bytes): {peaks}",
              f"check: reference replay of {len(batches)} supersteps took "
              f"{check_s:.3f} s"]
     if trace:
@@ -210,7 +215,7 @@ def run(cell: Cell, *, seed: int, seconds: float, trace: bool,
     }
     return Outcome(metrics=metrics, run=run_data, checks=checks,
                    attempted=attempted, failed=failed,
-                   memory_peak_bytes=peak, notes=notes)
+                   memory_peak_bytes=max(peaks), notes=notes)
 
 
 def _replay(cell: Cell, seed: int, low: bool) -> Replay:

@@ -10,7 +10,8 @@ import jax
 import pytest
 
 from chip import deployment, harness, xplane
-from chip.tests.cpu import benchmark, cells, run_tiny, tiny, tiny_cell
+from chip.tests.cpu import (benchmark, cell_chips, cells, four_chip_stream,
+                            on_chips, run_tiny, tiny, tiny_cell)
 
 CELLS = cells()
 LOADED = {w: harness.load_cell(w) for w in CELLS}
@@ -23,9 +24,22 @@ def _with(cell: harness.Cell, **config) -> harness.Cell:
                                config=harness._merge(cell.config, config))
 
 
+def _config_user(path):
+    """The first cell that runs the configuration file ``path``."""
+    bench = benchmark()
+    names = [c["name"] for c in bench["configs"]
+             if os.path.join(harness.ROOT, c["file"]) == path]
+    assert names, f"{path} is no configuration of BENCHMARK.json"
+    users = [w["name"] for w in bench["workloads"] if w["config"] == names[0]]
+    assert users, f"no cell runs {names[0]}"
+    return users[0]
+
+
 @pytest.mark.parametrize("workload", CELLS)
+@on_chips()
 def test_cell_has_a_runner_and_a_reader_for_each_metric(workload):
     cell = LOADED[workload]
+    devices = jax.devices()[:cell.chips]
     mod = harness.runner(cell.traffic["mode"])
     assert callable(mod.run) and callable(mod.control)
     e2e = {m["name"] for m in cell.end_to_end}
@@ -34,7 +48,7 @@ def test_cell_has_a_runner_and_a_reader_for_each_metric(workload):
         assert callable(harness.reader(m["name"]))
         assert m["moves"] in e2e
     line = harness.result_line(tiny(cell), run_tiny(workload), trace=False,
-                               devices=jax.devices())
+                               devices=devices)
     assert set(line["metrics"]) == e2e
     assert all(m["value"] > 0 for m in line["metrics"].values())
     # the CPU has no device trace; every other reader finds its number
@@ -43,19 +57,14 @@ def test_cell_has_a_runner_and_a_reader_for_each_metric(workload):
     if program:
         traced = harness.result_line(tiny(cell),
                                      run_tiny(workload, trace=True),
-                                     trace=True, devices=jax.devices())
+                                     trace=True, devices=devices)
         assert set(traced["metrics"]) == program
 
 
 @pytest.mark.parametrize("path", CONFIGS, ids=os.path.basename)
+@on_chips(lambda path: cell_chips(_config_user(path)))
 def test_every_config_file_runs_at_its_tiny_size(path):
-    bench = benchmark()
-    names = [c["name"] for c in bench["configs"]
-             if os.path.join(harness.ROOT, c["file"]) == path]
-    assert names, f"{path} is no configuration of BENCHMARK.json"
-    users = [w["name"] for w in bench["workloads"] if w["config"] == names[0]]
-    assert users, f"no cell runs {names[0]}"
-    out = run_tiny(users[0])
+    out = run_tiny(_config_user(path))
     assert out.correct, out.checks
 
 
@@ -85,6 +94,7 @@ def test_a_kronecker_graph_adapts_in_batch():
     ({"session": {"placement_passes": 3}}, "placement_passes"),
     ({"session": {"tie_break": "stay"}}, "tie_break 'stay'"),
 ], ids=["generator", "graph-key", "session-key", "tie-rule"])
+@on_chips()
 def test_a_key_the_runner_does_not_honour_is_refused(workload, change, says):
     cell = _with(tiny_cell(workload), **change)
     with pytest.raises(harness.BenchError, match=says):
@@ -98,25 +108,47 @@ def test_the_batch_runner_refuses_the_streams_keys():
 
 
 @pytest.mark.parametrize("workload", CELLS)
+@on_chips()
 def test_a_sharded_backend_is_built_or_refused(workload):
     """``cluster_backend: sharded`` builds the sharded backend where the
-    cell has a chip per partition, and is refused where it has not."""
+    cell has a chip per partition, and is refused where it has not: more
+    partitions than the cell's chips are refused, and a cell whose own
+    backend is sharded builds at its own k on its own chips."""
     from repro.api.backend import ShardedBackend
+    own = LOADED[workload].config["session"]
     cell = _with(tiny_cell(workload), session={"cluster_backend": "sharded"})
     stream = cell.traffic["mode"] == "stream"
     graph = deployment.graph(cell, 7, cell.config["session"].get(
         "stream_edge_slots", 0))
-    k = cell.config["session"]["k"]
+    devices = jax.devices()[:cell.chips]
+    k = len(devices) + 1
     with pytest.raises(harness.BenchError, match=f"needs {k} devices"):
-        deployment.session(cell, graph, 7, trace=False,
-                           devices=jax.devices(), stream=stream)
-    one = _with(cell, session={"k": 1})
-    system = deployment.session(one, graph, 7, trace=False,
-                                devices=jax.devices()[:1], stream=stream)
+        deployment.session(_with(cell, session={"k": k}), graph, 7,
+                           trace=False, devices=devices, stream=stream)
+    built = own["k"] if own["cluster_backend"] == "sharded" else cell.chips
+    system = deployment.session(_with(cell, session={"k": built}), graph, 7,
+                                trace=False, devices=devices, stream=stream)
     assert isinstance(system.backend, ShardedBackend)
 
 
+@on_chips(lambda: four_chip_stream().chips)
+def test_a_four_chip_stream_runs_on_four_host_devices():
+    """The harness holds a cell on four chips: the Kronecker stream on the
+    sharded backend, one partition a chip, runs on four host devices, is
+    held correct against the reference, and its traced window holds the
+    cluster step's spans."""
+    cell = four_chip_stream()
+    out = run_tiny(cell, trace=True)
+    assert out.correct and out.failed == 0, out.checks
+    names = {e["name"] for e in out.run["spans"] if e.get("type") == "span"}
+    assert {"cluster/bucket", "cluster/dispatch"} <= names
+    line = harness.result_line(cell, out, trace=True,
+                               devices=jax.devices()[:cell.chips])
+    assert line["device"]["count"] == 4
+
+
 @pytest.mark.parametrize("workload", STREAM)
+@on_chips()
 def test_a_traced_stream_names_its_gaps_by_the_captures_spans(
         workload, monkeypatch):
     """The program's phase spans reach the trace reduction as the capture
@@ -141,6 +173,7 @@ def test_a_traced_stream_names_its_gaps_by_the_captures_spans(
 
 @pytest.mark.parametrize("workload", [
     w for w in STREAM if LOADED[w].traffic["rate_share_of_knee"] > 1])
+@on_chips()
 def test_a_saturated_stream_opens_the_window_with_a_full_batch(workload):
     """Above capacity the queue never empties in steady state, so the
     window's first superstep takes a full batch (``lead_seconds``), as

@@ -12,10 +12,12 @@ two kinds of events, as plain dicts (``kind``, ``track``, ``name``,
 recorded list (``tests/fixtures``):
 
 * busy: the union of op intervals inside the window, per chip, averaged
-  over the chips;
-* ops: total device time per op (named by ``op_name``), and of the ops
-  whose name contains a kernel's pattern;
-* idle gaps: the stretches of the window in which the chip ran nothing,
+  over the chips the cell ran on (a chip with no op in the window is
+  wholly idle);
+* ops: total device time per op (named by ``op_name``), of the ops whose
+  name contains a kernel's pattern, and of the collective ops (per chip,
+  averaged like busy);
+* idle gaps: the stretches of the window in which a chip ran nothing,
   each named by the innermost host span (program span or annotation)
   running at its midpoint.
 """
@@ -23,10 +25,15 @@ from __future__ import annotations
 
 import glob
 import os
+import re
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 OP_LINES = ("XLA Ops",)
 ANNOTATION_PREFIX = "bench/"
+# the opcodes of HLO ops that move data between chips (an async pair's
+# ``-start`` and ``-done`` halves included), as they follow the op's shape
+COLLECTIVE = re.compile(r" (all-gather|all-reduce|reduce-scatter|"
+                        r"collective-permute|all-to-all)(-start|-done)?\(")
 
 
 def load(logdir: str, lines: Optional[Dict[str, int]] = None
@@ -53,6 +60,7 @@ def load(logdir: str, lines: Optional[Dict[str, int]] = None
                     if device:
                         events.append({"kind": "op", "track": plane.name,
                                        "name": op_name(ev.name),
+                                       "collective": is_collective(ev.name),
                                        "start_ns": float(ev.start_ns),
                                        "dur_ns": float(ev.duration_ns)})
                     elif ev.name.startswith(ANNOTATION_PREFIX):
@@ -96,20 +104,31 @@ def _clip(a: float, b: float, lo: float, hi: float) -> Optional[Tuple]:
     return (a, b) if b > a else None
 
 
+def is_collective(hlo: str) -> bool:
+    """Whether the op, as the trace writes it (``%all-gather.8 = s32[...]
+    all-gather(...)``), moves data between chips: read from its opcode, not
+    its name, which JAX may take from the primitive (``%psum_invariant.24
+    = s32[4]{0:T(128)} all-reduce(...)``)."""
+    return COLLECTIVE.search(hlo) is not None
+
+
 def reduce(events: Sequence[Dict], window: Tuple[float, float], *,
-           kernel: Optional[str] = None,
-           spans: Sequence[Dict] = ()) -> Dict:
+           kernel: Optional[str] = None, spans: Sequence[Dict] = (),
+           chips: int = 1) -> Dict:
     """Device numbers of ``window`` (start_ns, end_ns on the trace's clock).
 
     ``spans`` are extra host spans already on that clock (dicts with
     ``name``, ``start_ns``, ``dur_ns``, ``depth``) — the program's own
-    phases — that name the idle gaps alongside the annotations.
+    phases — that name the idle gaps alongside the annotations. ``chips``
+    is the number of chips the cell ran on: busy, idle and collective time
+    are averaged over them, or over the tracks that ran an op where those
+    are more.
     """
     lo, hi = window
     by_track: Dict[str, List[Tuple[float, float]]] = {}
     op_time: Dict[str, float] = {}
-    kernel_ns = 0.0
-    kernel_count = 0
+    kernel_ns = collective_ns = 0.0
+    kernel_count = collective_count = 0
     for ev in events:
         if ev["kind"] != "op":
             continue
@@ -121,25 +140,34 @@ def reduce(events: Sequence[Dict], window: Tuple[float, float], *,
         if kernel and kernel in ev["name"]:
             kernel_ns += iv[1] - iv[0]
             kernel_count += 1
+        if ev.get("collective"):
+            collective_ns += iv[1] - iv[0]
+            collective_count += 1
     busy = {t: _merge(iv) for t, iv in by_track.items()}
-    busy_ns = [sum(b - a for a, b in merged) for merged in busy.values()]
+    # a chip that ran nothing in the window is one gap the whole window long
+    idle_chips = max(chips - len(busy), 0)
+    per_chip = list(busy.values()) + [[]] * idle_chips
+    n = max(len(per_chip), 1)
+    busy_ns = sum(b - a for merged in per_chip for a, b in merged)
     host = [dict(ev, depth=-1) for ev in events if ev["kind"] == "host"]
     host += [dict(s) for s in spans]
     gaps: Dict[str, float] = {}
-    for merged in busy.values():
+    for merged in per_chip:
         edges = [lo] + [x for ab in merged for x in ab] + [hi]
         for a, b in zip(edges[::2], edges[1::2]):
             if b > a:
                 name = _cover(host, a, b)
-                gaps[name] = gaps.get(name, 0.0) + (b - a) / len(busy)
+                gaps[name] = gaps.get(name, 0.0) + (b - a) / n
     top_ops = sorted(op_time.items(), key=lambda kv: -kv[1])[:10]
     top_gaps = sorted(gaps.items(), key=lambda kv: -kv[1])[:10]
     return {
         "window_s": (hi - lo) / 1e9,
-        "busy_s": (sum(busy_ns) / len(busy_ns) / 1e9) if busy_ns else 0.0,
-        "chips": len(busy),
+        "busy_s": busy_ns / n / 1e9 if busy else 0.0,
+        "chips": len(per_chip),
         "kernel_s": kernel_ns / 1e9,
         "kernel_events": kernel_count,
+        "collective_s": collective_ns / n / 1e9,
+        "collective_events": collective_count,
         "device_ops": [[name, ns / 1e9] for name, ns in top_ops],
         "idle_gaps": [[name, ns / 1e9] for name, ns in top_gaps],
     }
